@@ -23,11 +23,11 @@ def _bench_compressed_psum(full: bool) -> dict:
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.dist.compat import shard_map
     from repro.dist.compression import compressed_psum, compression_ratio
+    from repro.dist.sharding import make_mesh
 
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev,), ("data",))
+    mesh = make_mesh((n_dev,), ("data",))
     sizes = [1 << 16, 1 << 20] + ([1 << 22] if full else [])
     results = {}
     for size in sizes:
@@ -35,9 +35,9 @@ def _bench_compressed_psum(full: bool) -> dict:
 
         def reduce_with(fn):
             return jax.jit(
-                shard_map(
+                jax.shard_map(
                     fn, mesh=mesh, in_specs=(P(),), out_specs=P(),
-                    manual_axes={"data"},
+                    axis_names={"data"}, check_vma=False,
                 )
             )
 

@@ -345,7 +345,7 @@ def device_producer_smoke(n_iters: int = 4) -> dict:
     from jax.sharding import PartitionSpec as P
 
     from repro.core import instrument
-    from repro.dist.compat import set_mesh, shard_map
+    from repro.dist.sharding import make_mesh
 
     n_dev = len(jax.devices())
     gov = Governor()
@@ -356,15 +356,16 @@ def device_producer_smoke(n_iters: int = 4) -> dict:
     bus = instrument.get_event_bus()
     bus.subscribe(gov)
     try:
-        mesh = jax.make_mesh((n_dev,), ("r",))
+        mesh = make_mesh((n_dev,), ("r",))
         from repro.core.instrument import cd_psum
 
         def f(x):
             return cd_psum(x, "r")
 
-        with set_mesh(mesh):
-            g = jax.jit(shard_map(f, mesh=mesh, in_specs=P("r"),
-                                  out_specs=P("r"), manual_axes=("r",)))
+        with jax.set_mesh(mesh):
+            g = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("r"),
+                                      out_specs=P("r"), axis_names={"r"},
+                                      check_vma=False))
             x = jnp.arange(float(n_dev))
             for _ in range(n_iters):
                 jax.block_until_ready(g(x))

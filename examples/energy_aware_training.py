@@ -23,7 +23,7 @@ from repro.core import instrument
 from repro.core.governor import Governor
 from repro.core.instrument import cd_psum
 from repro.core.policies import COUNTDOWN_SLACK
-from repro.dist.compat import set_mesh, shard_map
+from repro.dist.sharding import make_mesh
 from repro.models.inputs import make_batch
 from repro.models.transformer import init_params, loss_fn
 from repro.train.optimizer import OptConfig, adamw_update, init_opt_state
@@ -31,7 +31,7 @@ from repro.train.optimizer import OptConfig, adamw_update, init_opt_state
 
 def main() -> None:
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev,), ("data",))
+    mesh = make_mesh((n_dev,), ("data",))
     cfg = reduced(get_config("countdown-100m"), n_layers=2, d_model=64,
                   n_heads=4, n_kv_heads=2, d_ff=128, vocab=256)
     opt_cfg = OptConfig(lr=1e-3, warmup_steps=2, total_steps=30)
@@ -53,20 +53,18 @@ def main() -> None:
         params, opt, _ = adamw_update(params, grads, opt, opt_cfg)
         return params, opt, loss
 
-    # fully-specified jit shardings: the production idiom, and required on
-    # the pinned container jax (the profile-mode io_callback token otherwise
-    # desyncs XLA's sharding-propagation parameter vector)
+    # fully-specified jit shardings: the production idiom
     repl = NamedSharding(mesh, P())
     dsh = NamedSharding(mesh, P("data"))
     params = jax.device_put(params, jax.tree.map(lambda _: repl, params))
     opt = jax.device_put(opt, jax.tree.map(lambda _: repl, opt))
     step = jax.jit(
-        shard_map(
+        jax.shard_map(
             per_device_step,
             mesh=mesh,
             in_specs=(P(), P(), P("data")),
             out_specs=(P(), P(), P()),
-            manual_axes={"data"},
+            axis_names={"data"}, check_vma=False,
         ),
         in_shardings=(
             jax.tree.map(lambda _: repl, params),
@@ -81,7 +79,7 @@ def main() -> None:
     )
 
     print(f"data-parallel training on {n_dev} devices, COUNTDOWN Slack live:")
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for i in range(30):
             batch = make_batch(cfg, batch=8, seq_len=33, seed=i, kind="train")
             batch = {k: jax.device_put(v, dsh) for k, v in batch.items()}

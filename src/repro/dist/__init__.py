@@ -3,9 +3,10 @@
 The COUNTDOWN Slack core (``repro.core``) reasons about *when* ranks wait;
 this package provides the machinery that makes ranks exist at scale:
 
-``sharding``     partition rules: FSDP/TP parameter shardings (2-d and
-                 ZeRO-3), optimizer-state mirroring, batch/cache layouts,
-                 tensor-parallel serving rules, activation constraints.
+``sharding``     the Auto-axis mesh factory and partition rules: FSDP/TP
+                 parameter shardings (2-d and ZeRO-3), optimizer-state
+                 mirroring, batch/cache layouts, tensor-parallel serving
+                 rules, activation constraints.
 ``elastic``      :class:`ElasticMesh` (rebuildable device mesh that survives
                  node failures) and :class:`FailureInjector` (deterministic
                  fault injection for the recovery path).
@@ -17,9 +18,6 @@ this package provides the machinery that makes ranks exist at scale:
 ``straggler``    :class:`StragglerDetector` — turns barrier-arrival events
                  into a per-rank laggard signal (the paper's critical-rank
                  analysis, §5, made online).
-``compat``       small shims over jax API renames (``set_mesh``,
-                 ``shard_map``) so the same drivers run on the pinned
-                 container jax and on current releases.
 
 See DESIGN.md §3 for how these compose into the train/serve launchers.
 """

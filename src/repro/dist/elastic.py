@@ -18,7 +18,8 @@ import itertools
 from typing import List, Optional, Sequence, Tuple
 
 import jax
-import numpy as np
+
+from repro.dist.sharding import make_mesh
 
 
 class ElasticMesh:
@@ -52,10 +53,7 @@ class ElasticMesh:
         if n == 0:
             raise RuntimeError("ElasticMesh: no healthy devices left to build from")
         mp = max(g for g in range(1, min(model_parallel, n) + 1) if n % g == 0)
-        grid = np.empty((n // mp, mp), dtype=object)
-        for i, d in enumerate(devs):
-            grid[i // mp, i % mp] = d
-        return jax.sharding.Mesh(grid, self.axis_names)
+        return make_mesh((n // mp, mp), self.axis_names, devices=devs)
 
 
 class FailureInjector:

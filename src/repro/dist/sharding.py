@@ -14,6 +14,13 @@ One table of *requested* specs plus one safety pass:
   degrades to a legal one on any mesh (1-device smoke runs, 7-survivor
   elastic rebuilds, 512-device dry-runs) instead of failing to compile.
 
+``make_mesh`` is the program's one mesh factory.  Every mesh it builds has
+``AxisType.Auto`` axes: the rules below *request* layouts and XLA
+propagates the rest, which is the semantics this code was written for
+(``jax.make_mesh`` alone now defaults to ``Explicit`` axes, under which
+ops such as ``jnp.take`` refuse operands whose sharding is not spelled
+out).
+
 Entry points (all return pytrees of ``NamedSharding`` matching the input):
 
   ``param_shardings``       2-d FSDPxTP (default) or ``mode="zero3"``;
@@ -21,9 +28,10 @@ Entry points (all return pytrees of ``NamedSharding`` matching the input):
                             over the pod axis (the explicit cross-pod-reduce
                             step); ``gather_safe=True`` additionally drops
                             tensor-parallel entries so each leaf is sharded
-                            along at most the FSDP axes — the layout whose
-                            all-gathers stay legal inside a partial-manual
-                            ``shard_map`` region.
+                            along at most the FSDP axes, and keeps the
+                            embedding (a gather operand) replicated — the
+                            layout whose all-gathers and gathers stay legal
+                            inside a partial-manual ``shard_map`` region.
   ``opt_state_shardings``   mirrors the parameter rules onto m/v/master.
   ``batch_shardings``       batch dim over the data axes (all axes in zero3).
   ``cache_shardings``       KV/recurrent-state layout; ``serve_tp=True``
@@ -38,9 +46,22 @@ from __future__ import annotations
 from typing import Any, FrozenSet, Iterable, Optional, Tuple, Union
 
 import jax
-from jax.sharding import NamedSharding, PartitionSpec as P
+import numpy as np
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 Axes = Union[None, str, Tuple[str, ...]]
+
+
+def make_mesh(shape: Tuple[int, ...], axis_names: Tuple[str, ...],
+              devices: Optional[Iterable[Any]] = None) -> Mesh:
+    """A mesh with ``AxisType.Auto`` axes over ``devices`` (default: all
+    of them, laid out by ``jax.make_mesh``); an explicit device list is
+    placed row-major, in the order given."""
+    axis_types = (AxisType.Auto,) * len(axis_names)
+    if devices is None:
+        return jax.make_mesh(shape, axis_names, axis_types=axis_types)
+    grid = np.asarray(list(devices), dtype=object).reshape(shape)
+    return Mesh(grid, axis_names, axis_types=axis_types)
 
 # leaf-name rule tables -------------------------------------------------------
 # column-parallel: (d_in -> fsdp, d_out -> model)
@@ -152,9 +173,15 @@ def param_shardings(
         if mode == "zero3":
             spec = _zero3_spec(mesh, x.shape, zero3_axes)
         else:
-            spec = _param_spec(_leaf_name(path), x.ndim, fsdp)
+            name = _leaf_name(path)
+            spec = _param_spec(name, x.ndim, fsdp)
             if gather_safe:
-                spec = P(*(None if e == "model" else e for e in tuple(spec)))
+                # XLA's SPMD partitioner aborts (ExpandDeviceGroupsWithIota)
+                # when it must reshard a gather operand inside a
+                # partial-manual region, so the token-embedding table stays
+                # whole there
+                spec = P() if name == "embed" else P(
+                    *(None if e == "model" else e for e in tuple(spec)))
         return NamedSharding(mesh, sanitize_spec(mesh, spec, x.shape))
 
     return jax.tree_util.tree_map_with_path(leaf, params)

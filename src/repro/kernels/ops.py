@@ -1,17 +1,13 @@
 """Jit'd dispatch wrappers for the Pallas kernels.
 
-On a CPU host (this container) the kernels execute in interpret mode —
-the kernel body runs as traced JAX ops, validating BlockSpec indexing and
-numerics; on a TPU backend the same call sites compile to Mosaic.
-
-``REPRO_PALLAS_INTERPRET=0/1`` overrides the platform default (CI forces
-the interpret branch explicitly; TPU users can A/B interpret mode).  The
-flag is read at trace time: wrappers are jitted, so flipping the env var
-after a shape has compiled does not retrace it.
+The platform alone picks how a kernel runs: on a CPU backend the kernel
+body executes in interpret mode (traced JAX ops, validating BlockSpec
+indexing, DMAs and numerics); on a TPU backend the same call sites always
+compile with Mosaic.  There is no override, so a chip run can never
+quietly fall back to the interpreter.
 """
 from __future__ import annotations
 
-import os
 from functools import partial
 
 import jax
@@ -29,12 +25,7 @@ from repro.kernels.ssd import ssd_pallas
 
 
 def _interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET", "").strip().lower()
-    if env in ("1", "true", "yes", "on"):
-        return True
-    if env in ("0", "false", "no", "off"):
-        return False
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 @partial(jax.jit, static_argnames=("eps", "row_block"))

@@ -5,29 +5,29 @@ query token per slot against that slot's K/V pages, addressed through a
 per-request page table.  The XLA reference in ``serve/kvcache.py``
 materializes a contiguous ``(B, M*page, Hkv, D)`` gather every step;
 this kernel never does — the page table is a *scalar-prefetch* operand,
-so the kernel body reads ``table[b, j]`` itself and pulls exactly one
-physical page at a time out of the HBM-resident pool.
+so the kernel body reads ``table[b, j]`` itself and pulls exactly the
+physical pages it names out of the HBM-resident pool.
 
-TPU mapping: grid = (batch, kv_heads) — one program per (slot, kv head).
-The K/V pools are ``memory_space=ANY`` operands (they stay in HBM; only
-the touched pages ever move on-chip), and the kernel body walks the
-request's table row with a ``fori_loop``, pulling two pages' K/V tiles
-per iteration (a ``(2*page, D)`` block; odd trailing pages are padded by
-a self-masking re-load of the last entry) and folding each block into an
-online-softmax carry (m, l, acc) exactly like ``flash_attention.py``
-folds k-blocks.
-Keeping the page walk *inside* the program — rather than as a third,
-sequential grid dimension — means the per-program dispatch cost is paid
-``B*Hkv`` times instead of ``B*Hkv*M`` times, which is what makes the
-kernel profitable even in interpret mode on CPU hosts; on a compiled
-Mosaic build each page read lowers to a local HBM→VMEM copy (the
-``pltpu.make_async_copy`` idiom, which also enables prefetching page
-``j+1`` while page ``j`` is in the MXU).  The GQA group of
-``G = Hq // Hkv`` query heads rides along as the block's row dimension,
-so the score tile is a single ``(G, page)`` MXU matmul.  Under
-``kv_quant`` the pages are int8 with per-(token, head) float32 scale
-pages; dequant is fused into the page load (one multiply on the tile
-already on-chip) instead of materializing a dequantized cache.
+TPU mapping: grid = (batch,) — one program per slot, every KV head at
+once.  The K/V pools are ``memory_space=ANY`` operands (they stay in
+HBM): the program walks its table row with a ``fori_loop`` and, per
+iteration, DMAs two whole pages ``(page, Hkv, D)`` of each pool
+(``pltpu.make_async_copy``) into one half of a double-buffered VMEM
+scratch while it attends the pair already on-chip.  Mosaic only loads
+from VMEM/SMEM refs, so the ANY-space pools are never indexed directly.
+A page tile is read as a flat ``(page*Hkv, D)`` matrix (row
+``t*Hkv + h`` is token ``t`` of head ``h``) and all ``Hkv*G`` query
+rows score it in one ``(Hkv*G, 2*page*Hkv)`` MXU matmul; each query row
+keeps only its own head's columns, masked exactly like future
+positions.  That trades a few idle MXU columns for no per-head strided
+loads.  Each block is folded into an online-softmax carry (m, l, acc)
+exactly like ``flash_attention.py`` folds k-blocks.  Under
+``kv_quant`` the pages are int8 with per-(token, head) float32 scales;
+a ``(P, page, Hkv)`` scale pool has too narrow a minor dim to slice, so
+the kernel sees it as ``(P, 1, page*Hkv)`` — one lane-dense row per
+page, laid out like the flat page tile's rows — and applies the K
+scales to the score columns and the V scales to the probabilities
+(dequant fused into the tile already on-chip, no dequantized cache).
 
 Causal masking needs no query position: decode queries sit at position
 ``pos[b]`` and every stored key at ``j*page + offset`` is valid iff it
@@ -42,13 +42,15 @@ the new token's K/V row (and scale rows) land at
 — an in-place block write into the existing page arrays, bit-identical
 to the ``.at[page_idx, off].set()`` path (tier-1 asserted) without XLA's
 copy-on-donate round trip.  The serving engine uses the *fused* form
-(``paged_attention_scatter_pallas``): with a ``(B, Hkv)`` grid the
-scatter is a prologue of the attention program itself — program (b, h)
-writes only slot ``b``'s row at head ``h`` and then walks only slot
-``b``'s pages, so the in-place store can never race another program's
-page reads, and the whole read-modify-attend step is one dispatch.
-(Idle slots are parked on the scratch page, which every program's mask
-excludes, so even a torn scratch write is unobservable.)
+(``paged_attention_scatter_pallas``): the scatter is a prologue of the
+attention program itself.  Program b DMAs slot b's new ``(Hkv, D)`` K/V
+rows (blocked ``(1, Hkv, D)``, which meets the (8, 128) tiling rule)
+into their page, read-modify-writes the page's flat scale row under
+``kv_quant``, waits for those writes, and only then starts its walk,
+which reads only slot b's pages — so the in-place store can never race
+another program's page reads, and the whole read-modify-attend step is
+one dispatch.  (Idle slots are parked on the scratch page, which every
+program's mask excludes, so even a torn scratch write is unobservable.)
 """
 from __future__ import annotations
 
@@ -64,80 +66,111 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _load_page(pool_ref, scale_ref, pid, h):
-    """One (page, D) tile out of an ANY-space pool, dequantized in-flight
-    when the pool carries int8 pages + a float32 scale pool."""
-    tile = pool_ref[pl.ds(pid, 1), :, h, :][0].astype(jnp.float32)
-    if scale_ref is not None:
-        tile = tile * scale_ref[pl.ds(pid, 1), :, h][0][:, None]
-    return tile
+def _flat_scales(s_pages):
+    """(P, page, Hkv) scale pool -> (P, 1, page*Hkv): one lane-dense row
+    per page, the only shape in which a narrow-minor float32 page can be
+    DMA'd (Mosaic slices the lane dim in whole 128-lane tiles)."""
+    p, page, hkv = s_pages.shape
+    return s_pages.reshape(p, 1, page * hkv)
 
 
-def _page_walk(tbl_ref, b, h, q, p0, k_ref, v_ref, ks_ref, vs_ref,
-               *, scale: float, window: int, page: int, n_pages: int):
-    """Online-softmax walk over one request's table row, two pages per
-    iteration (halves loop-carry overhead; the score tile is a single
-    ``(G, 2*page)`` MXU matmul).  For odd ``n_pages`` the trailing
-    phantom page re-loads the last table entry, but its key positions
-    ``>= n_pages * page`` exceed every legal ``pos`` — the causal mask
-    zeroes it, so no separate epilogue iteration is needed.  Returns the
-    normalized (G, D) float32 attention output."""
-    g, d = q.shape
+def _page_walk(tbl_ref, pos_ref, b, q, pools, bufs, sems,
+               *, scale: float, window: int, page: int, n_pages: int,
+               hkv: int, g: int):
+    """Online-softmax walk over slot ``b``'s table row, two pages per
+    iteration, every KV head at once.
+
+    ``pools`` are the ANY-space (HBM) page pools: ``(k, v)`` or ``(k, v,
+    k_scale, v_scale)``.  Each iteration DMAs two whole pages (all heads)
+    of each pool into one half of a double-buffered VMEM scratch while
+    the other half is attended, so the next pair is in flight during the
+    current pair's matmuls.  A page tile ``(page, Hkv, D)`` is read as a
+    flat ``(page*Hkv, D)`` matrix: row ``t*Hkv + h`` is token ``t`` of
+    head ``h``.  The score tile is one ``(Hkv*G, 2*page*Hkv)`` MXU matmul
+    in which each query row keeps only its own head's columns (the rest
+    are masked like future positions), so no per-head strided load is
+    needed.  For odd ``n_pages`` the trailing phantom page re-loads the
+    last table entry; its key positions ``>= n_pages * page`` exceed every
+    legal ``pos``, so the causal mask zeroes it.  Returns the normalized
+    ``(Hkv*G, D)`` float32 attention output."""
+    quant = len(pools) == 4
+    d = q.shape[-1]
+    rows, cols = hkv * g, 2 * page * hkv
+    n_iter = (n_pages + 1) // 2
+    p0 = pos_ref[b]
+
+    def copies(jj, slot):
+        j0 = 2 * jj
+        pids = (tbl_ref[b, j0], tbl_ref[b, jnp.minimum(j0 + 1, n_pages - 1)])
+        return [pltpu.make_async_copy(pool.at[pid], buf.at[slot, i], sems.at[slot])
+                for i, pid in enumerate(pids) for pool, buf in zip(pools, bufs)]
+
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+    own_head = (col % hkv) == (row // g)
+    tok = col // hkv                                   # token within the pair
 
     def body(jj, carry):
         m_prev, l_prev, acc = carry
-        j0 = 2 * jj
-        j1 = jnp.minimum(j0 + 1, n_pages - 1)
-        pa = tbl_ref[b, j0]                            # the gather
-        pb = tbl_ref[b, j1]
-        k = jnp.concatenate(
-            [_load_page(k_ref, ks_ref, pa, h), _load_page(k_ref, ks_ref, pb, h)],
-            axis=0,
-        )                                              # (2*page, D)
-        v = jnp.concatenate(
-            [_load_page(v_ref, vs_ref, pa, h), _load_page(v_ref, vs_ref, pb, h)],
-            axis=0,
-        )
+        slot = jj % 2
 
-        s = (q @ k.T) * scale                          # (G, 2*page) — MXU
-        iota = jax.lax.broadcasted_iota(jnp.int32, (g, page), 1)
-        k_pos = jnp.concatenate(
-            [j0 * page + iota, (j0 + 1) * page + iota], axis=1
-        )                                              # phantom half masks itself
-        valid = k_pos <= p0
+        @pl.when(jj + 1 < n_iter)
+        def _prefetch():
+            for c in copies(jj + 1, 1 - slot):
+                c.start()
+
+        for c in copies(jj, slot):
+            c.wait()
+        k = bufs[0].at[slot].reshape(cols, d)[...].astype(jnp.float32)
+        v = bufs[1].at[slot].reshape(cols, d)[...].astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        if quant:                                      # dequant on the scores
+            ks = jnp.concatenate([bufs[2][slot, 0], bufs[2][slot, 1]], axis=1)
+            s = s * ks                                 # (rows, cols) * (1, cols)
+        s = s * scale
+        k_pos = 2 * jj * page + tok
+        valid = own_head & (k_pos <= p0)
         if window:
             valid &= k_pos > p0 - window
         s = jnp.where(valid, s, NEG_INF)
 
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)                         # (G, 2*page)
+        p = jnp.exp(s - m_new)                         # (rows, cols)
         corr = jnp.exp(m_prev - m_new)
         l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc = acc * corr + p @ v                       # (G, D) — MXU
+        if quant:                                      # ... and on the probs
+            vs = jnp.concatenate([bufs[3][slot, 0], bufs[3][slot, 1]], axis=1)
+            p = p * vs
+        acc = acc * corr + jnp.dot(p, v, preferred_element_type=jnp.float32)
         return m_new, l_new, acc
 
-    init = (jnp.full((g, 1), NEG_INF, jnp.float32),    # m (running max)
-            jnp.zeros((g, 1), jnp.float32),            # l (running denom)
-            jnp.zeros((g, d), jnp.float32))            # acc (weighted values)
-    m_f, l_f, acc = jax.lax.fori_loop(0, (n_pages + 1) // 2, body, init)
+    for c in copies(0, 0):
+        c.start()
+    init = (jnp.full((rows, 1), NEG_INF, jnp.float32),  # m (running max)
+            jnp.zeros((rows, 1), jnp.float32),          # l (running denom)
+            jnp.zeros((rows, d), jnp.float32))          # acc (weighted values)
+    _, l_f, acc = jax.lax.fori_loop(0, n_iter, body, init)
     return acc / jnp.maximum(l_f, 1e-20)
 
 
-def _paged_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
-                  scale: float, window: int, page: int, n_pages: int,
-                  quant: bool):
-    if quant:
-        ks_ref, vs_ref, o_ref = rest
-    else:
-        ks_ref = vs_ref = None
-        o_ref, = rest
+def _walk_scratch(pools):
+    """Double-buffered VMEM page tiles (2 slots x 2 pages) for each pool,
+    plus DMA semaphores: one per walk slot, then one each for the
+    prologue's row writes and its scale-row read-modify-write."""
+    bufs = [pltpu.VMEM((2, 2) + a.shape[1:], a.dtype) for a in pools]
+    return bufs + [pltpu.SemaphoreType.DMA((4,))]
+
+
+def _paged_kernel(tbl_ref, pos_ref, q_ref, *rest, n_pools: int, **kw):
+    pools = rest[:n_pools]
+    o_ref = rest[n_pools]
+    bufs = rest[n_pools + 1:2 * n_pools + 1]
+    sems = rest[2 * n_pools + 1]
     b = pl.program_id(0)
-    h = pl.program_id(1)
-    q = q_ref[0, 0].astype(jnp.float32)                # (G, D)
-    out = _page_walk(tbl_ref, b, h, q, pos_ref[b], k_ref, v_ref, ks_ref,
-                     vs_ref, scale=scale, window=window, page=page,
-                     n_pages=n_pages)
-    o_ref[0, 0] = out.astype(o_ref.dtype)
+    q = q_ref[0].astype(jnp.float32)                   # (Hkv*G, D)
+    out = _page_walk(tbl_ref, pos_ref, b, q, pools, bufs, sems, **kw)
+    o_ref[0] = out.astype(o_ref.dtype)
 
 
 def paged_attention_pallas(
@@ -163,70 +196,78 @@ def paged_attention_pallas(
     bsz, hkv, g, d = q.shape
     page = k_pages.shape[1]
     m = table.shape[1]
-    quant = k_scale_pages is not None
-    scale = 1.0 / math.sqrt(d)
+    pools = [k_pages, v_pages]
+    if k_scale_pages is not None:
+        pools += [_flat_scales(k_scale_pages), _flat_scales(v_scale_pages)]
 
     # table/pos are scalar-prefetch operands: available before the body
-    # runs, so the fori_loop can chase ``table[b, j]`` page indices.  The
-    # pools are ANY-space refs — never block-mapped, only the pages the
-    # loop touches are read.
-    q_spec = pl.BlockSpec((1, 1, g, d), lambda b, h, tbl, ps: (b, h, 0, 0))
-    kv_spec = pl.BlockSpec(memory_space=pltpu.ANY)
-    in_specs = [q_spec, kv_spec, kv_spec]
-    args = [q, k_pages, v_pages]
-    if quant:
-        in_specs += [kv_spec, kv_spec]
-        args += [k_scale_pages, v_scale_pages]
-
+    # runs, so the walk can chase ``table[b, j]`` page indices.  The pools
+    # are ANY-space refs — never block-mapped; only the pages the walk
+    # names are DMA'd into VMEM.
+    q_spec = pl.BlockSpec((1, hkv * g, d), lambda b, *_: (b, 0, 0))
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(bsz, hkv),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, d), lambda b, h, tbl, ps: (b, h, 0, 0)),
+        grid=(bsz,),
+        in_specs=[q_spec] + [any_spec] * len(pools),
+        out_specs=q_spec,
+        scratch_shapes=_walk_scratch(pools),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(
-            _paged_kernel, scale=scale, window=window, page=page,
-            n_pages=m, quant=quant,
+            _paged_kernel, n_pools=len(pools), scale=1.0 / math.sqrt(d),
+            window=window, page=page, n_pages=m, hkv=hkv, g=g,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bsz, hkv, g, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((bsz, hkv * g, d), q.dtype),
         interpret=interpret,
-    )(table, pos, *args)
+    )(table, pos, q.reshape(bsz, hkv * g, d), *pools)
+    return out.reshape(bsz, hkv, g, d)
 
 
 def _paged_step_kernel(tbl_ref, pos_ref, pidx_ref, offw_ref, q_ref, *rest,
-                       scale: float, window: int, page: int, n_pages: int,
-                       quant: bool):
-    # rest = (*new_rows, *pool_inputs, o, *pool_outputs); the pool outputs
-    # alias the pool inputs, so the body only ever touches the output refs
-    if quant:
-        kn_ref, vn_ref, ksn_ref, vsn_ref = rest[:4]
-        o_ref, k_ref, v_ref, ks_ref, vs_ref = rest[8:]
-    else:
-        kn_ref, vn_ref = rest[:2]
-        ks_ref = vs_ref = None
-        o_ref, k_ref, v_ref = rest[4:]
+                       n_pools: int, **kw):
+    # rest = (*new_rows, *pool_inputs, o, *pool_outputs, *bufs, sems); the
+    # pool outputs alias the pool inputs, so the body only ever touches
+    # the output refs
+    news = rest[:n_pools]
+    o_ref = rest[2 * n_pools]
+    pools = rest[2 * n_pools + 1:3 * n_pools + 1]
+    bufs = rest[3 * n_pools + 1:4 * n_pools + 1]
+    sems = rest[4 * n_pools + 1]
     b = pl.program_id(0)
-    h = pl.program_id(1)
-
-    # scatter prologue: land the new token's row in its page (in place,
-    # aliased) *before* the walk, so the walk attends to it.  Program
-    # (b, h) writes only slot b's row at head h and reads only slot b's
-    # pages at head h — no cross-program hazard.
     pw = pidx_ref[b]
     ow = offw_ref[b]
-    k_ref[pl.ds(pw, 1), pl.ds(ow, 1), h, :] = kn_ref[0, 0][None, None, :]
-    v_ref[pl.ds(pw, 1), pl.ds(ow, 1), h, :] = vn_ref[0, 0][None, None, :]
-    if quant:
-        ks_ref[pl.ds(pw, 1), pl.ds(ow, 1), h] = ksn_ref[0, 0][None, None]
-        vs_ref[pl.ds(pw, 1), pl.ds(ow, 1), h] = vsn_ref[0, 0][None, None]
+    page, hkv = kw["page"], kw["hkv"]
 
-    q = q_ref[0, 0].astype(jnp.float32)                # (G, D)
-    out = _page_walk(tbl_ref, b, h, q, pos_ref[b], k_ref, v_ref, ks_ref,
-                     vs_ref, scale=scale, window=window, page=page,
-                     n_pages=n_pages)
-    o_ref[0, 0] = out.astype(o_ref.dtype)
+    # scatter prologue: land the new token's row (every head) in its page,
+    # in place (aliased), *before* the walk, so the walk attends to it.
+    # Program b writes only slot b's row and reads only slot b's pages —
+    # no cross-program hazard.  K/V rows are one (Hkv, D) tile each; a
+    # scale row is a lane slice of its page's flat row, so it goes in by
+    # read-modify-write of that row through the walk buffer.
+    rows = [pltpu.make_async_copy(news[i].at[0], pools[i].at[pw, ow], sems.at[2])
+            for i in range(2)]
+    for c in rows:
+        c.start()
+    if n_pools == 4:
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, page * hkv), 1)
+        mine = lane // hkv == ow
+        for i in (2, 3):
+            tile = bufs[i].at[0, 0]
+            c = pltpu.make_async_copy(pools[i].at[pw], tile, sems.at[3])
+            c.start()
+            c.wait()
+            tile[...] = jnp.where(mine, news[i][0], tile[...])
+            c = pltpu.make_async_copy(tile, pools[i].at[pw], sems.at[3])
+            c.start()
+            c.wait()
+    for c in rows:
+        c.wait()
+
+    q = q_ref[0].astype(jnp.float32)                   # (Hkv*G, D)
+    out = _page_walk(tbl_ref, pos_ref, b, q, pools, bufs, sems, **kw)
+    o_ref[0] = out.astype(o_ref.dtype)
 
 
 def paged_attention_scatter_pallas(
@@ -261,38 +302,44 @@ def paged_attention_scatter_pallas(
     page = k_pages.shape[1]
     m = table.shape[1]
     quant = k_scale_pages is not None
-    scale = 1.0 / math.sqrt(d)
 
-    q_spec = pl.BlockSpec((1, 1, g, d), lambda b, h, *s: (b, h, 0, 0))
-    row_spec = pl.BlockSpec((1, 1, d), lambda b, h, *s: (b, h, 0))
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+    q_spec = pl.BlockSpec((1, hkv * g, d), lambda b, *_: (b, 0, 0))
+    row_spec = pl.BlockSpec((1, hkv, d), lambda b, *_: (b, 0, 0))
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    news = [k_new, v_new]
+    pools = [k_pages, v_pages]
+    new_specs = [row_spec, row_spec]
     if quant:
-        srow_spec = pl.BlockSpec((1, 1), lambda b, h, *s: (b, h))
-        new_specs = [row_spec, row_spec, srow_spec, srow_spec]
-        news = [k_new, v_new, k_scale_new, v_scale_new]
-        pools = [k_pages, v_pages, k_scale_pages, v_scale_pages]
-    else:
-        new_specs = [row_spec, row_spec]
-        news = [k_new, v_new]
-        pools = [k_pages, v_pages]
+        # a scale row travels as the whole flat page row it lands in,
+        # repeated per token; the prologue keeps only lanes of token ``off``
+        srow_spec = pl.BlockSpec((1, 1, page * hkv), lambda b, *_: (b, 0, 0))
+        news += [jnp.tile(s, (1, page))[:, None] for s in (k_scale_new, v_scale_new)]
+        pools += [_flat_scales(k_scale_pages), _flat_scales(v_scale_pages)]
+        new_specs += [srow_spec, srow_spec]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,                 # table, pos, page_idx, off
-        grid=(bsz, hkv),
+        grid=(bsz,),
         in_specs=[q_spec] + new_specs + [any_spec] * len(pools),
         out_specs=[q_spec] + [any_spec] * len(pools),
+        scratch_shapes=_walk_scratch(pools),
     )
     base = 4 + 1 + len(news)                   # scalar-prefetch + q + rows
     out = pl.pallas_call(
-        functools.partial(_paged_step_kernel, scale=scale, window=window,
-                          page=page, n_pages=m, quant=quant),
+        functools.partial(_paged_step_kernel, n_pools=len(pools),
+                          scale=1.0 / math.sqrt(d), window=window, page=page,
+                          n_pages=m, hkv=hkv, g=g),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((bsz, hkv, g, d), q.dtype)]
+        out_shape=[jax.ShapeDtypeStruct((bsz, hkv * g, d), q.dtype)]
                   + [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in pools],
         input_output_aliases={base + i: 1 + i for i in range(len(pools))},
         interpret=interpret,
-    )(table, pos, page_idx, off, q, *news, *pools)
-    return out[0], tuple(out[1:])
+    )(table, pos, page_idx, off, q.reshape(bsz, hkv * g, d), *news, *pools)
+    new_pools = list(out[1:])
+    if quant:
+        new_pools[2:] = [a.reshape(s.shape) for a, s in
+                         zip(new_pools[2:], (k_scale_pages, v_scale_pages))]
+    return out[0].reshape(bsz, hkv, g, d), tuple(new_pools)
 
 
 def _scatter_kernel(pi_ref, off_ref, *refs, n_arrays: int):

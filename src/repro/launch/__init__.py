@@ -6,13 +6,14 @@
             with paged KV, Poisson arrivals, and governor-priced slack.
 ``dryrun``  AOT sweep: lower + compile every (arch x shape x mesh) cell.
 ``mesh``    production/host mesh constructors.
+``compile_cache``  where the persistent compilation cache lives.
 
 Submodules import jax and are loaded lazily (PEP 562) so that
 ``import repro.launch`` stays cheap for tooling.
 """
 import importlib
 
-_SUBMODULES = ("dryrun", "mesh", "serve", "train")
+_SUBMODULES = ("compile_cache", "dryrun", "mesh", "serve", "train")
 
 __all__ = list(_SUBMODULES)
 

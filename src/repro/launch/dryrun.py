@@ -43,7 +43,6 @@ import numpy as np
 from repro.configs import ARCHS, SHAPES, cell_is_runnable, get_config
 from repro.core import instrument
 from repro.dist import sharding as SH
-from repro.dist.compat import set_mesh
 from repro.launch.mesh import make_production_mesh
 from repro.obs import log as obslog
 
@@ -305,7 +304,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, *, force=False,
         mesh, mode="zero3" if zero3 else "2d"))
     instrument.set_mode(instrument_mode)
     try:
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             # ---- phase 1: scanned module -> memory analysis (production) --
             t0 = time.time()
             fn, args_s, in_sh, out_sh, donate = build_cell(

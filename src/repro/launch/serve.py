@@ -18,8 +18,8 @@
 Timing excludes compilation: one warmup generate runs before the clock
 starts and the compile time is printed separately.  On a multi-chip host
 this applies ``serve_param_shardings`` (TP weights) and, in continuous
-mode, ``page_pool_shardings`` for the paged KV pool; on this container it
-runs single-device.
+mode, ``page_pool_shardings`` for the paged KV pool; on one device it
+runs unsharded.
 """
 from __future__ import annotations
 
@@ -35,7 +35,7 @@ from repro.core.events import EventBus
 from repro.core.governor import Governor
 from repro.core.policies import policy_for_theta
 from repro.dist import sharding as SH
-from repro.dist.compat import set_mesh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.models.hooks import install_constraint
 from repro.models.inputs import make_batch
@@ -89,7 +89,12 @@ def _make_requests(args, cfg) -> list:
     return reqs
 
 
-def _run_continuous(args, cfg, params, mesh, n_dev: int, mp: int) -> None:
+def _run_continuous(args, cfg, params, mesh, n_dev: int, mp: int) -> dict:
+    """Serve ``args.n_requests`` Poisson arrivals through one engine.
+
+    Returns what it logs: the engine, the finished requests, token count,
+    wall and compile seconds, the governor report and the SLO summary.
+    """
     max_len = args.prompt_len + args.steps + args.page_size
     max_len += (-max_len) % args.page_size
     eng = ContinuousEngine(cfg, params, n_slots=args.slots, max_len=max_len,
@@ -216,6 +221,9 @@ def _run_continuous(args, cfg, params, mesh, n_dev: int, mp: int) -> None:
         path = trace_rec.save(args.trace_out)
         log.info("trace_out", records=trace_rec.n_seen,
                  dropped=trace_rec.n_dropped, path=path)
+    return {"engine": eng, "requests": done, "tokens": n_tok, "wall_s": dt,
+            "tok_per_s": n_tok / dt, "compile_s": t_compile, "report": rep,
+            "slo": s}
 
 
 def _fleet_trace(args):
@@ -348,7 +356,7 @@ def _run_fleet_real(args, cfg, params) -> None:
     _fleet_metrics_out(args, fill)
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="llama3.2-1b")
     ap.add_argument("--reduced", action="store_true")
@@ -419,9 +427,13 @@ def main() -> None:
                          "and unaffected")
     obslog.add_flags(ap)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
-    obslog.configure_from_args(args)
+    return ap
 
+
+def setup(args):
+    """Config, weights and mesh for parsed ``args``: returns ``(cfg,
+    params, mesh, n_devices, model_parallel)`` with the weights random
+    from ``PRNGKey(0)`` and placed by the serving partition rules."""
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
@@ -430,13 +442,21 @@ def main() -> None:
 
     n = len(jax.devices())
     mp = max(g for g in range(1, args.model_parallel + 1) if n % g == 0)
-    mesh = jax.make_mesh((n // mp, mp), ("data", "model"))
+    mesh = SH.make_mesh((n // mp, mp), ("data", "model"))
     install_constraint(SH.activation_constraint_fn(mesh))
 
     params = init_params(cfg, jax.random.PRNGKey(0))
     if mp > 1 or n > 1:
         psh = SH.serve_param_shardings(mesh, params)
         params = jax.tree.map(lambda a, s: jax.device_put(a, s), params, psh)
+    return cfg, params, mesh, n, mp
+
+
+def main(argv=None):
+    enable_compile_cache()
+    args = build_parser().parse_args(argv)
+    obslog.configure_from_args(args)
+    cfg, params, mesh, n, mp = setup(args)
 
     if not args.continuous and not args.fleet and (
             args.theta or args.trace_out or args.power_cap > 0
@@ -447,14 +467,14 @@ def main() -> None:
                     why="--theta/--trace-out/--power-cap/telemetry need the "
                         "continuous engine's governor (add --continuous)")
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         if args.fleet:
             if args.autoscale:
                 _run_fleet_sim(args, cfg)
             else:
                 _run_fleet_real(args, cfg, params)
         elif args.continuous:
-            _run_continuous(args, cfg, params, mesh, n, mp)
+            return _run_continuous(args, cfg, params, mesh, n, mp)
         else:
             _run_static(args, cfg, params)
 

@@ -1,7 +1,7 @@
 """Production training driver: checkpoint/restart, simulated node failures,
 elastic re-meshing, straggler telemetry, COUNTDOWN instrumentation.
 
-Example (this container):
+Example (CPU host):
   PYTHONPATH=src python -m repro.launch.train --arch countdown-100m \
       --steps 20 --batch 8 --seq 128 --checkpoint-dir /tmp/ckpt \
       --save-every 5 --fail-at 12
@@ -25,8 +25,8 @@ from repro.core.governor import Governor
 from repro.core.policies import policy_for_theta
 from repro.dist import sharding as SH
 from repro.dist.checkpoint import CheckpointManager
-from repro.dist.compat import set_mesh
 from repro.dist.elastic import ElasticMesh, FailureInjector
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.hooks import install_constraint
 from repro.obs import log as obslog
 from repro.train.data import DataLoader
@@ -60,11 +60,14 @@ def build_live(mesh, cfg, opt_cfg, state_host):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.core.instrument import cd_psum
-    from repro.dist.compat import shard_map
     from repro.models.transformer import loss_fn
     from repro.train.optimizer import adamw_update
 
     n_data = int(mesh.shape["data"])
+    # every mesh axis is manual in this region, where a sharding
+    # constraint may name none of them: clear whatever hook an earlier
+    # build (or a server in the same process) installed
+    install_constraint(None)
 
     def per_device_step(state, batch):
         params, opt = state["params"], state["opt"]
@@ -80,14 +83,12 @@ def build_live(mesh, cfg, opt_cfg, state_host):
     dsh = NamedSharding(mesh, P("data"))
     state = jax.device_put(state_host,
                            jax.tree.map(lambda _: repl, state_host))
-    # fully-specified jit shardings: required on the pinned container jax
-    # (the profile-mode io_callback token otherwise desyncs XLA's
-    # sharding-propagation parameter vector)
+    # fully-specified jit shardings: the production idiom
     step_fn = jax.jit(
-        shard_map(
+        jax.shard_map(
             per_device_step, mesh=mesh,
             in_specs=(P(), P("data")), out_specs=(P(), P()),
-            manual_axes=set(mesh.axis_names),
+            axis_names=set(mesh.axis_names), check_vma=False,
         ),
         in_shardings=(
             jax.tree.map(lambda _: repl, state),
@@ -99,7 +100,10 @@ def build_live(mesh, cfg, opt_cfg, state_host):
     return state, step_fn
 
 
-def main() -> None:
+def main(argv=None) -> dict:
+    """Run the driver; returns the last logged loss, the step reached, the
+    device count and (under ``--instrument profile``) the governor report."""
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", default="countdown-100m")
     ap.add_argument("--reduced", action="store_true", help="smoke-size config")
@@ -113,13 +117,18 @@ def main() -> None:
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--fail-at", type=int, default=0,
                     help="simulate a node failure at this step (fault-tolerance demo)")
-    ap.add_argument("--instrument", choices=["off", "barrier", "profile"], default="off")
+    ap.add_argument("--instrument", choices=["off", "barrier", "profile"],
+                    default=None,
+                    help="cd_* mode (default off; profile with --live-events "
+                         "or the event-consuming flags below)")
     ap.add_argument("--live-events", action="store_true",
                     help="run the step as a fully-manual data-parallel shard_map "
                          "with cd_psum gradient reduction, so host phase events "
                          "actually fire (the jit path's XLA-inserted collectives "
-                         "cannot emit them); implies --instrument profile and "
-                         "data parallelism only")
+                         "cannot emit them); data parallelism only.  Implies "
+                         "--instrument profile unless --instrument is given: "
+                         "'--instrument off' is the same step uninstrumented, "
+                         "the baseline for the instrumentation's overhead")
     ap.add_argument("--theta", default="",
                     help="governor timeout: seconds (e.g. 500e-6), 'auto' for "
                          "the online ThetaTuner (cntd_adaptive policy), or "
@@ -154,7 +163,7 @@ def main() -> None:
                          "sink throughput")
     obslog.add_flags(ap)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     obslog.configure_from_args(args)
 
     cfg = get_config(args.arch)
@@ -176,8 +185,11 @@ def main() -> None:
         log.warning("live_events_dp_only", model_parallel=args.model_parallel,
                     using=1)
         args.model_parallel = 1
-    if (args.trace_out or args.power_cap > 0 or args.theta or obs_on
-            or args.live_events) and args.instrument != "profile":
+    needs_events = bool(args.trace_out or args.power_cap > 0 or args.theta
+                        or obs_on)
+    if args.instrument is None:
+        args.instrument = "profile" if needs_events or args.live_events else "off"
+    elif needs_events and args.instrument != "profile":
         # the recorder records events, the tenant polls interval snapshots,
         # the telemetry stack consumes both, and the governor/tuner feeds
         # them all: everything is empty (a silent no-op) without the
@@ -258,12 +270,13 @@ def main() -> None:
 
     t_start = time.time()
     step = start_step
+    loss = float("nan")
     # mesh-epoch loop: each epoch runs under one mesh; a failure breaks out,
     # rebuilds the mesh from the surviving devices and restores the latest
     # checkpoint (the 1000-node recovery path, scaled down)
     while step < args.steps:
         failed_device = None
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             while step < args.steps:
                 failed_device = injector.check(step)
                 if failed_device is not None:
@@ -274,8 +287,9 @@ def main() -> None:
                 if mgr and step % args.save_every == 0:
                     mgr.save(step, jax.device_get(state))
                 if step % max(1, args.steps // 20) == 0 or step == args.steps:
+                    loss = float(metrics["loss"])
                     log.info(
-                        "step", step=step, loss=float(metrics["loss"]),
+                        "step", step=step, loss=loss,
                         grad_norm=float(metrics["grad_norm"]),
                         lr=float(metrics["lr"]),
                         s_per_step=(time.time() - t_start)
@@ -334,6 +348,7 @@ def main() -> None:
         # governor is still subscribed, then drop back to per-event mode
         instrument.flush_events()
         instrument.set_ingest_mode("event")
+    rep = None
     if args.instrument == "profile":
         rep = governor.finalize()
         log.info("governor", calls=rep.n_calls, downshifts=rep.n_downshifts,
@@ -380,6 +395,8 @@ def main() -> None:
         instrument.enable_events(False)
     bus = instrument.get_event_bus()
     bus.unsubscribe(governor)
+    return {"loss": loss, "steps": step, "n_devices": len(em.healthy_devices()),
+            "report": rep}
 
 
 if __name__ == "__main__":

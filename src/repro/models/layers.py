@@ -29,6 +29,18 @@ def dtype_of(name: str):
     return {"float32": jnp.float32, "bfloat16": jnp.bfloat16, "float16": jnp.float16}[name]
 
 
+def compute_weights(cfg, p):
+    """One block's weight matrices in ``cfg.compute_dtype`` (mixed
+    precision: ``param_dtype`` master weights, compute-dtype matmuls), so
+    the residual stream keeps the compute dtype through every block.
+    Vectors (norm scales, SSM / RG-LRU gate parameters) keep their
+    storage dtype; the code reading them upcasts where it needs to."""
+    dtype = dtype_of(cfg.compute_dtype)
+    return jax.tree.map(
+        lambda a: a.astype(dtype)
+        if a.ndim >= 2 and jnp.issubdtype(a.dtype, jnp.floating) else a, p)
+
+
 # --------------------------------------------------------------------------
 # initializers
 # --------------------------------------------------------------------------

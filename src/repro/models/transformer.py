@@ -104,6 +104,7 @@ def param_count(params: Params) -> int:
 
 def _block_forward(cfg, kind: str, p: Params, x, positions):
     """One block, full-sequence.  Returns (x, aux)."""
+    p = L.compute_weights(cfg, p)
     aux = jnp.zeros((), jnp.float32)
     if kind == "attn":
         h = L.apply_norm(cfg, p["ln1"], x)
@@ -246,6 +247,7 @@ def init_cache(cfg, batch: int, max_len: int) -> Params:
 
 
 def _block_prefill(cfg, kind, p, x, positions, bc):
+    p = L.compute_weights(cfg, p)
     if kind == "attn":
         h = L.apply_norm(cfg, p["ln1"], x)
         y, bc = L.attention_prefill(cfg, p["attn"], h, positions, bc)
@@ -274,6 +276,7 @@ def _block_decode(cfg, kind, p, x, pos, bc, attn_fn=None):
     """One block's single-token step.  ``attn_fn(p_attn, h, bc) -> (y, bc)``
     overrides the dense-cache attention (the paged serving engine passes a
     page-table closure); everything else is shared."""
+    p = L.compute_weights(cfg, p)
     if kind == "attn":
         h = L.apply_norm(cfg, p["ln1"], x)
         if attn_fn is None:

@@ -83,12 +83,17 @@ class DataLoader:
         return batch
 
     def _worker(self) -> None:
+        # a batch that met a full queue is offered again, never replaced:
+        # the stream must not depend on how slowly the consumer drains it
+        b = None
         while not self._stop.is_set():
-            b = self._make()
+            if b is None:
+                b = self._make()
             try:
                 self._q.put(b, timeout=1.0)
             except queue.Full:
                 continue
+            b = None
 
     def __iter__(self) -> Iterator[Dict[str, Any]]:
         return self

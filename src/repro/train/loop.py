@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.instrument import cd_psum
-from repro.dist.compat import LEGACY_PARTIAL_MANUAL, shard_map
 from repro.dist.compression import compressed_psum
 from repro.models.transformer import loss_fn
 from repro.train.optimizer import OptConfig, adamw_update, init_opt_state
@@ -106,70 +105,39 @@ def make_pod_train_step(
         summed = cd_psum(grads, "pod")
         return jax.tree.map(lambda g: g / npod, summed)
 
-    def _local_grads(params, batch, constraint=None):
-        """Per-shard forward/backward under ``constraint`` (None = no
-        activation hints: required in fully-manual regions, where wsc would
-        name manual axes)."""
+    def per_pod(params, batch):
+        # within-pod sharding is auto (XLA over the data axes); only the
+        # cross-pod reduction is explicit + instrumented.  Constraints
+        # inside the manual-'pod' region must not name 'pod', nor shard
+        # the logits over 'model': XLA's SPMD partitioner aborts when it
+        # reshards the loss's vocab gather across the region's device
+        # groups.
+        from repro.dist.sharding import activation_constraint_fn
         from repro.models import hooks
 
         old = hooks._CONSTRAIN
-        hooks.install_constraint(constraint)
+        hooks.install_constraint(
+            activation_constraint_fn(mesh, exclude={"pod", "model"}))
         try:
             if train_cfg.microbatch:
-                return _accumulated_grads(cfg, params, batch, train_cfg.microbatch)
-            return _grads(cfg, params, batch)
+                loss, _, grads = _accumulated_grads(
+                    cfg, params, batch, train_cfg.microbatch)
+            else:
+                loss, _, grads = _grads(cfg, params, batch)
         finally:
             hooks.install_constraint(old)
+        grads = reduce_grads(grads)
+        loss = jax.lax.pmean(loss, "pod")
+        return loss, grads
 
-    if LEGACY_PARTIAL_MANUAL:
-        # Legacy XLA aborts (IsManualSubgroup checks) whenever auto-sharded
-        # operands cross a *partial*-manual shard_map boundary, so on these
-        # versions the region is FULLY manual: parameters are gathered at
-        # entry (the gather_safe layouts keep that a plain FSDP all-gather)
-        # and each device computes its pod's full batch shard — split over
-        # 'pod' only, exactly like the partial-manual path, so per-region
-        # batch semantics (e.g. the microbatch divisibility contract) are
-        # identical across jax versions; intra-pod replicas are then
-        # reconciled with an explicit pmean.  The cross-pod reduction is
-        # the same instrumented collective in both variants.
-        intra = tuple(a for a in mesh.axis_names if a != "pod")
-
-        def per_device(params, batch):
-            loss, _, grads = _local_grads(params, batch)
-            grads = reduce_grads(grads)                      # cross-pod, cd_*
-            grads = jax.tree.map(lambda g: jax.lax.pmean(g, intra), grads)
-            loss = jax.lax.pmean(loss, ("pod",) + intra)
-            return loss, grads
-
-        region = shard_map(
-            per_device,
-            mesh=mesh,
-            in_specs=(P(), P("pod")),
-            out_specs=(P(), P()),
-            manual_axes=set(mesh.axis_names),
-        )
-    else:
-
-        def per_pod(params, batch):
-            # within-pod sharding is auto (XLA over data/model); only the
-            # cross-pod reduction is explicit + instrumented.  Constraints
-            # inside the manual-'pod' region must not name 'pod'.
-            from repro.dist.sharding import activation_constraint_fn
-
-            loss, _, grads = _local_grads(
-                params, batch, activation_constraint_fn(mesh, exclude={"pod"})
-            )
-            grads = reduce_grads(grads)
-            loss = jax.lax.pmean(loss, "pod")
-            return loss, grads
-
-        region = shard_map(
-            per_pod,
-            mesh=mesh,
-            in_specs=(P(), P("pod")),
-            out_specs=(P(), P()),
-            manual_axes={"pod"},
-        )
+    region = jax.shard_map(
+        per_pod,
+        mesh=mesh,
+        in_specs=(P(), P("pod")),
+        out_specs=(P(), P()),
+        axis_names={"pod"},
+        check_vma=False,
+    )
 
     def train_step(state: Dict[str, Any], batch: Dict[str, Any]):
         loss, grads = region(state["params"], batch)

@@ -248,11 +248,11 @@ def test_paged_attention_scatter_fuses_bit_equal(quant, window):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-def test_interpret_env_override(monkeypatch):
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-    assert ops._interpret() is True
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+def test_interpret_follows_platform(monkeypatch):
+    """Interpret mode on a CPU backend, Mosaic on any other — decided by
+    the platform alone, with no environment override."""
+    assert ops._interpret() is (jax.default_backend() == "cpu")
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
     assert ops._interpret() is False
-    monkeypatch.delenv("REPRO_PALLAS_INTERPRET")
-    # platform default: interpret everywhere except a real TPU backend
-    assert ops._interpret() is (jax.default_backend() != "tpu")
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "cpu")
+    assert ops._interpret() is True

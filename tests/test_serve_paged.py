@@ -246,7 +246,8 @@ def test_page_pool_shardings_rules():
     state = PagedKVPool(cfg_ssm, n_slots=2, max_len=32, page=8).blocks
     sh = page_pool_shardings(mesh, state)
     # recurrent per-slot state: slot (batch) dim over the data axes
-    assert sh["stack"]["0"]["conv"].spec[1] == ("data",)
+    # (compared as specs: ``P`` normalises a 1-tuple entry to its axis name)
+    assert sh["stack"]["0"]["conv"].spec == P(None, ("data",), None, "model")
 
 
 def test_poisson_arrivals_shape_and_bursts():

@@ -42,6 +42,24 @@ def test_training_reduces_loss():
     assert last < first - 0.25, (first, last)
 
 
+def test_data_loader_stream_ignores_consumer_speed():
+    """A consumer that leaves the prefetch queue full for longer than the
+    worker's put timeout (a first-step compile) sees the same batches, in
+    the same order, as one that drains it at once."""
+    import time
+
+    cfg = _tiny_cfg()
+    fast = DataLoader(cfg, batch=2, seq_len=17, seed=3)
+    want = [np.asarray(next(fast)["tokens"]) for _ in range(5)]
+    fast.close()
+    slow = DataLoader(cfg, batch=2, seq_len=17, seed=3)
+    time.sleep(2.5)                          # > the worker's 1 s put timeout
+    got = [np.asarray(next(slow)["tokens"]) for _ in range(5)]
+    slow.close()
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(g, w)
+
+
 def test_checkpoint_restart_resumes_identically():
     cfg = _tiny_cfg()
     opt_cfg = OptConfig(lr=1e-3, warmup_steps=2, total_steps=30)
@@ -93,9 +111,8 @@ def test_multidevice_fsdp_and_instrumented_pod_step():
         from repro.train.optimizer import OptConfig
         from repro.models.inputs import make_batch
         from repro.core import instrument
-        from repro.dist.compat import set_mesh
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = SH.make_mesh((2, 2, 2), ("pod", "data", "model"))
         cfg = reduced(get_config("llama3.2-1b"))
         opt_cfg = OptConfig(warmup_steps=2, total_steps=10)
         state = init_state(cfg, opt_cfg, jax.random.PRNGKey(0))
@@ -107,7 +124,7 @@ def test_multidevice_fsdp_and_instrumented_pod_step():
                  "opt": jax.device_put(state["opt"], os_)}
         batch = jax.device_put(batch, bs)
         install_constraint(SH.activation_constraint_fn(mesh))
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             auto = jax.jit(make_train_step(cfg, opt_cfg))
             s1, m1 = auto(state, batch)
             assert jnp.isfinite(m1["loss"])
@@ -148,7 +165,6 @@ def test_elastic_restart_on_smaller_mesh():
         from repro.configs import get_config, reduced
         from repro.dist import sharding as SH
         from repro.dist.checkpoint import CheckpointManager
-        from repro.dist.compat import set_mesh
         from repro.dist.elastic import ElasticMesh
         from repro.models.hooks import install_constraint
         from repro.train.loop import make_train_step, init_state
@@ -164,7 +180,7 @@ def test_elastic_restart_on_smaller_mesh():
         batch = make_batch(cfg, batch=8, seq_len=32, kind="train")
         with tempfile.TemporaryDirectory() as d:
             mgr = CheckpointManager(d)
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 step = jax.jit(make_train_step(cfg, opt_cfg))
                 state, m_before = step(state, batch)
                 mgr.save(1, state)
@@ -177,7 +193,7 @@ def test_elastic_restart_on_smaller_mesh():
             ps = SH.param_shardings(mesh2, state["params"])
             os_ = SH.opt_state_shardings(mesh2, ps, state["opt"])
             _, restored = mgr.restore_latest(skel, {"params": ps, "opt": os_})
-            with set_mesh(mesh2):
+            with jax.set_mesh(mesh2):
                 step2 = jax.jit(make_train_step(cfg, opt_cfg))
                 restored, m_after = step2(restored, batch)
                 assert jnp.isfinite(m_after["loss"])

@@ -326,7 +326,6 @@ def test_async_pair_jax_numerics_and_event_order():
 
     from repro.core import instrument
     from repro.core.instrument import cd_psum_async, cd_wait
-    from repro.dist.compat import set_mesh, shard_map
 
     mesh = jax.make_mesh((1,), ("r",))
     events = []
@@ -339,9 +338,9 @@ def test_async_pair_jax_numerics_and_event_order():
         y = x * 2.0                                   # overlapped compute
         return cd_wait(h) + y
 
-    with set_mesh(mesh):
-        g = shard_map(f, mesh=mesh, in_specs=P("r"), out_specs=P("r"),
-                      manual_axes=("r",))
+    with jax.set_mesh(mesh):
+        g = jax.shard_map(f, mesh=mesh, in_specs=P("r"), out_specs=P("r"),
+                          axis_names={"r"}, check_vma=False)
         x = jnp.arange(4.0)
         res = jax.block_until_ready(jax.jit(g)(x))
     np.testing.assert_allclose(np.asarray(res), np.asarray(x) * 3.0)
@@ -357,7 +356,6 @@ def test_blocking_wrappers_numerics_and_events_per_mode():
 
     from repro.core import instrument
     from repro.core.instrument import cd_all_gather, cd_pmean, cd_ppermute, cd_psum
-    from repro.dist.compat import set_mesh, shard_map
 
     mesh = jax.make_mesh((1,), ("r",))
     x = jnp.arange(4.0)
@@ -374,11 +372,11 @@ def test_blocking_wrappers_numerics_and_events_per_mode():
             d = cd_ppermute(x, "r", [(0, 0)])
             return a + b + c + d
 
-        return shard_map(f, mesh=mesh, in_specs=P("r"), out_specs=P("r"),
-                         manual_axes=("r",))
+        return jax.shard_map(f, mesh=mesh, in_specs=P("r"), out_specs=P("r"),
+                             axis_names={"r"}, check_vma=False)
 
     results = {}
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for mode in ("off", "barrier", "profile"):
             instrument.set_mode(mode)
             instrument.enable_events(mode == "profile")
@@ -399,7 +397,6 @@ def test_compressed_psum_async_pair_matches_blocking():
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.dist.compat import set_mesh, shard_map
     from repro.dist.compression import (
         compressed_psum, compressed_psum_start, compressed_psum_wait,
     )
@@ -414,12 +411,12 @@ def test_compressed_psum_async_pair_matches_blocking():
         h = compressed_psum_start(g, "r")
         return compressed_psum_wait(h)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         spec = {"w": P(), "b": P()}
-        a = shard_map(blocking, mesh=mesh, in_specs=(spec,), out_specs=spec,
-                      manual_axes=("r",))(grads)
-        b = shard_map(split, mesh=mesh, in_specs=(spec,), out_specs=spec,
-                      manual_axes=("r",))(grads)
+        a = jax.shard_map(blocking, mesh=mesh, in_specs=(spec,), out_specs=spec,
+                          axis_names={"r"}, check_vma=False)(grads)
+        b = jax.shard_map(split, mesh=mesh, in_specs=(spec,), out_specs=spec,
+                          axis_names={"r"}, check_vma=False)(grads)
     for k in grads:
         np.testing.assert_array_equal(np.asarray(a[k]), np.asarray(b[k]))
 
