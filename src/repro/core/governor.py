@@ -59,6 +59,7 @@ from repro.core.pstate import DEFAULT_HW, HwModel
 from repro.core.timeout import (PredictiveTuner, PredictorDecision,
                                 ThetaDecision, ThetaTuner)
 from repro.dist.straggler import StragglerDetector
+from repro.obs.tracer import recording, span
 
 
 class Actuation(NamedTuple):
@@ -727,6 +728,15 @@ class Governor:
 
     # the bus consumer interface ----------------------------------------------
     def sink(self, rank: int, phase: str, call_id: int, t: float) -> None:
+        """One phase event (the span ``governor.sink`` while a profiler
+        session records)."""
+        if recording():
+            with span("governor.sink"):
+                self._sink(rank, phase, call_id, t)
+        else:
+            self._sink(rank, phase, call_id, t)
+
+    def _sink(self, rank: int, phase: str, call_id: int, t: float) -> None:
         with self._lock:
             # recorded under the lock: the trace order must be the order the
             # governor processed events in, or replay() loses bit-exactness

@@ -47,6 +47,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.core.events import PHASE_NAMES, BatchAccumulator, EventBus
+from repro.obs.tracer import recording
 
 AxisNames = Union[str, Sequence[str]]
 
@@ -183,10 +184,24 @@ def _emit(rank, phase_code, call_id) -> None:
     """Host-side callback: timestamp and publish onto the event bus —
     directly per event, or via the ingest accumulator when the batched
     spine is on (full buffers are queued, not delivered inline: an
-    ordered ``io_callback`` must not run consumer code)."""
+    ordered ``io_callback`` must not run consumer code).
+
+    While a profiler session records, the whole of it is the span
+    ``cd.emit`` with arguments ``rank``, ``phase``, ``call`` and ``t_ns``
+    (the timestamp handed to the bus, in ns)."""
     if not _BUS:
         return
-    t = time.monotonic()
+    if not recording():
+        _publish(rank, phase_code, call_id, time.monotonic())
+        return
+    with jax.profiler.TraceAnnotation("cd.emit") as s:
+        t = time.monotonic()
+        s.set_metadata(rank=int(rank), phase=PHASE_NAMES[int(phase_code)],
+                       call=int(call_id), t_ns=round(t * 1e9))
+        _publish(rank, phase_code, call_id, t)
+
+
+def _publish(rank, phase_code, call_id, t: float) -> None:
     acc = _ACC
     if acc is None:
         _BUS.publish(int(rank), PHASE_NAMES[int(phase_code)], int(call_id), t)

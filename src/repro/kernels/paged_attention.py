@@ -221,6 +221,7 @@ def paged_attention_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bsz, hkv * g, d), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(table, pos, q.reshape(bsz, hkv * g, d), *pools)
     return out.reshape(bsz, hkv, g, d)
 
@@ -334,6 +335,7 @@ def paged_attention_scatter_pallas(
                   + [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in pools],
         input_output_aliases={base + i: 1 + i for i in range(len(pools))},
         interpret=interpret,
+        name="paged_attention_scatter",
     )(table, pos, page_idx, off, q.reshape(bsz, hkv * g, d), *news, *pools)
     new_pools = list(out[1:])
     if quant:
@@ -393,5 +395,6 @@ def paged_scatter_pallas(
         # operand indices count the 2 scalar-prefetch refs
         input_output_aliases={2 + i: i for i in range(n)},
         interpret=interpret,
+        name="paged_attention_row_scatter",
     )(page_idx, off, *pages, *new_rows)
     return tuple(out)

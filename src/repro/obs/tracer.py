@@ -32,23 +32,58 @@ inside the 10% ``sink_throughput`` budget.  Direct bus subscription
 probes and tests — but pays a Python call per event, which the budget
 does not cover.
 
-Timestamps are host-monotonic seconds on capture and are rebased to the
-earliest captured instant on export (Chrome traces want microseconds from
-an arbitrary epoch).  Export ordering is deterministic: events are sorted
-by ``(ts, pid, tid, ph, name)`` with a stable sort, so the same capture
-always serializes to the same bytes — the golden-fixture property the
-conformance test pins.
+Timestamps are host-monotonic seconds on capture (every hook is handed
+``time.monotonic()``) and are rebased to the earliest captured instant on
+export (Chrome traces want microseconds from an arbitrary epoch); the
+epoch, in monotonic seconds, is written to ``otherData.monotonic_epoch_s``.
+Export ordering is deterministic: events are sorted by ``(ts, pid, tid,
+ph, name)`` with a stable sort, so the same capture always serializes to
+the same bytes — the golden-fixture property the conformance test pins.
 
 :func:`validate_trace` is the schema gate the tests and the CI smoke step
 run against produced artifacts.
+
+Program spans on the profiler's clock
+-------------------------------------
+:func:`span` is the other half: a named span written into the JAX
+profiler's own trace (``jax.profiler.start_trace``), on the clock of the
+device's events, and only while a profiler session records.  Names are
+``<layer>.<what>``: ``cd.emit`` (the host side of one instrumentation
+callback, whose ``t_ns`` argument is the ``time.monotonic()`` value the
+governor got, in ns: the span's start minus ``t_ns`` joins the profiler's
+clock to the governor's and to this module's Perfetto export),
+``governor.sink``, and the serving engine's ``engine.*`` spans.
 """
 from __future__ import annotations
 
 import collections
+import contextlib
 import json
 from typing import Any, Dict, List, Optional, Tuple
 
+import jax
+
 from repro.core.events import PhaseRecord
+
+try:    # ``recording()`` is True while a profiler session records
+    from jax._src.lib import _profiler
+
+    recording = _profiler.TraceMe.is_enabled
+except (ImportError, AttributeError):   # this jaxlib cannot tell: always annotate
+    def recording() -> bool:
+        return True
+
+NO_SPAN = contextlib.nullcontext()
+
+
+def span(name: str, **args):
+    """A ``jax.profiler.TraceAnnotation`` named ``name`` with ``args``
+    while a profiler session records, else the shared :data:`NO_SPAN`.
+    Callers whose arguments cost something to compute test
+    :func:`recording` first."""
+    if recording():
+        return jax.profiler.TraceAnnotation(name, **args)
+    return NO_SPAN
 
 # fixed track layout (process ids in the Chrome trace)
 PID_RANKS = 1
@@ -498,6 +533,7 @@ class SpanTracer:
                                    e["name"]))
         other = dict(self.meta)
         other["n_dropped"] = self.n_dropped
+        other["monotonic_epoch_s"] = t0
         return {"displayTimeUnit": "ms",
                 "traceEvents": meta_events + events,
                 "otherData": other}

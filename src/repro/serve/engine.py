@@ -31,6 +31,7 @@ import numpy as np
 from repro.models.transformer import decode_step as _decode
 from repro.models.transformer import init_cache, stack_layout
 from repro.models.transformer import prefill as _prefill
+from repro.obs.tracer import NO_SPAN, recording, span
 from repro.serve.kvcache import (
     SCRATCH_PAGE,
     PagedKVPool,
@@ -321,13 +322,16 @@ class ContinuousEngine:
             self._table[req.slot, len(req.pages)] = pid
             req.pages.append(pid)
 
-    def _retire(self, req: Request, sched: Scheduler, slo, now: float) -> None:
+    def _retire(self, req: Request, sched: Scheduler, slo, now: float,
+                t_start: float) -> None:
+        """``now`` is on the session's clock, which starts at ``t_start``
+        (``time.monotonic()``)."""
         if slo is not None:
             slo.on_finish(req, now)
         else:
             req.t_done = now
         if self.tracer is not None:
-            self.tracer.serve_event("evict", now, req.rid, req.slot)
+            self.tracer.serve_event("evict", t_start + now, req.rid, req.slot)
         drained = self._forced.pop(req.slot, None) is None
         if self.prefix_cache is not None and req.pages and drained:
             # adopt this request's written pages into the resident trie:
@@ -475,24 +479,34 @@ class EngineSession:
         self.sched.submit(req)
 
     def admit(self, now: Optional[float] = None) -> List[Request]:
-        """Join every arrived request that fits; returns the joins."""
+        """Join every arrived request that fits; returns the joins.
+
+        Under a recording profiler: the span ``engine.admit``, and in it one
+        ``engine.prefill`` per join with the request's ``rid``,
+        ``prompt_len`` and ``wait_us`` (from its arrival to the join's
+        start, on the session's clock)."""
         eng = self.engine
-        joins = self.sched.admit(self.now() if now is None else now)
-        for req in joins:
-            eng._join_request(req)
-            tnow = self.now()
-            if eng.tracer is not None:
-                eng.tracer.serve_event("join", tnow, req.rid, req.slot)
-            if req.out:
-                # prefill joins produce the first token immediately; prefix
-                # joins stay silent until the forced suffix drains
-                if self.slo is not None:
-                    self.slo.on_first_token(req, tnow)
-                else:
-                    req.t_first = req.t_prev = tnow
-            if not req.wants_more():
-                eng._retire(req, self.sched, self.slo, tnow)
-                self.finished.append(req)
+        with span("engine.admit"):
+            joins = self.sched.admit(self.now() if now is None else now)
+            for req in joins:
+                with (span("engine.prefill", rid=req.rid, prompt_len=len(req.prompt),
+                           wait_us=round(1e6 * (self.now() - req.arrival)))
+                      if recording() else NO_SPAN):
+                    eng._join_request(req)
+                tnow = self.now()
+                if eng.tracer is not None:
+                    eng.tracer.serve_event("join", self.t_start + tnow, req.rid,
+                                           req.slot)
+                if req.out:
+                    # prefill joins produce the first token immediately; prefix
+                    # joins stay silent until the forced suffix drains
+                    if self.slo is not None:
+                        self.slo.on_first_token(req, tnow)
+                    else:
+                        req.t_first = req.t_prev = tnow
+                if not req.wants_more():
+                    eng._retire(req, self.sched, self.slo, tnow, self.t_start)
+                    self.finished.append(req)
         return joins
 
     def sleep_until_next(self) -> bool:
@@ -513,28 +527,42 @@ class EngineSession:
             self.meter.idle(t0, t1)
 
     def decode_step(self) -> None:
-        """One batched decode step over all active slots."""
+        """One batched decode step over all active slots.
+
+        Under a recording profiler: the span ``engine.decode`` with four
+        children, ``engine.prepare`` (pages grown, the table clamped, the
+        tokens, lengths and table uploaded), ``engine.dispatch`` (the decode
+        call until it returns), ``engine.wait`` (until its output is ready)
+        and ``engine.commit`` (the readback and the per-slot bookkeeping,
+        retirements included)."""
+        eng = self.engine
+        with span("engine.decode"):
+            with span("engine.prepare"):
+                for req in self.sched.active.values():
+                    eng._grow_pages(req)
+                # clamp the table to the live pages: no request's K/V extends
+                # past ceil((max_pos + 1) / page) pages, so neither the XLA
+                # gather nor the pallas grid should pay O(max_len) per token.
+                # (Each distinct width is its own jit bucket — widths only
+                # grow, and there are at most max_pages_per_req of them.)
+                max_pos = int(eng._lengths.max())
+                m_live = min(eng._table.shape[1], max_pos // eng.pool.page + 1)
+                t0 = time.monotonic()
+                inputs = (jnp.asarray(eng._tokens), jnp.asarray(eng._lengths),
+                          jnp.asarray(eng._table[:, :m_live]))
+            with span("engine.dispatch"):
+                out, blocks = eng._decode(eng.params, *inputs, eng.pool.blocks)
+            with span("engine.wait"):
+                out = jax.block_until_ready(out)
+            t1 = time.monotonic()
+            with span("engine.commit"):
+                self._commit(out, blocks, t0, t1)
+        self.steps += 1
+
+    def _commit(self, out, blocks, t0: float, t1: float) -> None:
+        """A finished decode step's tokens, one per active slot."""
         eng = self.engine
         sched = self.sched
-        for req in sched.active.values():
-            eng._grow_pages(req)
-        # clamp the table to the live pages: no request's K/V extends past
-        # ceil((max_pos + 1) / page) pages, so neither the XLA gather nor
-        # the pallas grid should pay O(max_len) per token.  (Each distinct
-        # width is its own jit bucket — widths only grow, and there are at
-        # most max_pages_per_req of them.)
-        max_pos = int(eng._lengths.max())
-        m_live = min(eng._table.shape[1], max_pos // eng.pool.page + 1)
-        t0 = time.monotonic()
-        out, blocks = eng._decode(
-            eng.params,
-            jnp.asarray(eng._tokens),
-            jnp.asarray(eng._lengths),
-            jnp.asarray(eng._table[:, :m_live]),
-            eng.pool.blocks,
-        )
-        out = jax.block_until_ready(out)
-        t1 = time.monotonic()
         eng.pool.blocks = blocks
         if self.meter is not None:
             self.meter.step(t0, t1, sched.n_active, eng.n_slots)
@@ -571,6 +599,5 @@ class EngineSession:
                     req.t_first = tnow
                 req.t_prev = tnow
             if not req.wants_more():
-                eng._retire(req, sched, self.slo, tnow)
+                eng._retire(req, sched, self.slo, tnow, self.t_start)
                 self.finished.append(req)
-        self.steps += 1

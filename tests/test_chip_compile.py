@@ -76,3 +76,22 @@ def test_paged_kernel_compiles_for_v5e(one_chip, monkeypatch, variant):
     fn = jax.jit(getattr(ops, variant).__wrapped__)
     compiled = fn.lower(*_args(variant, one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("variant", [
+    "paged_attention", "paged_attention_quant",
+    "paged_attention_scatter", "paged_attention_scatter_quant",
+])
+def test_paged_kernel_is_named_for_the_trace(one_chip, monkeypatch, variant):
+    # the kernel's op keeps its own name inside a program of any other
+    # name: the trace (and ``paged_attention_roofline``) reads it by prefix
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    inner = getattr(ops, variant).__wrapped__
+
+    def serving_step(*args):
+        return inner(*args)
+
+    text = jax.jit(serving_step).lower(*_args(variant, one_chip)).compile().as_text()
+    kernels = [line.split(" = ", 1)[0].strip().lstrip("%") for line in text.splitlines()
+               if "tpu_custom_call" in line and " = " in line]
+    assert kernels and all(k.startswith("paged_attention") for k in kernels)

@@ -19,6 +19,7 @@ Three load-bearing guarantees live here:
 import io
 import json
 import os
+import time
 
 import pytest
 from hypothesis import given, settings, strategies as st
@@ -268,6 +269,41 @@ def test_governor_tap_production_wiring():
                 if e["ph"] == "i" and e["pid"] == TRACK_PIDS["governor"]]
     assert len(instants) == gov.n_actuations
     assert validate_trace(trace, require_tracks=("ranks", "governor")) == []
+
+
+def test_serve_instants_share_the_rank_spans_clock():
+    """A continuous serve run with a governor and a SpanTracer: the join
+    and evict instants land among the rank spans, on one monotonic clock
+    whose epoch the export names."""
+    import jax
+    import numpy as np
+
+    from repro.configs import get_config, reduced
+    from repro.models import init_params
+    from repro.serve import ContinuousEngine, Request
+
+    cfg = reduced(get_config("llama3.2-1b"))
+    eng = ContinuousEngine(cfg, init_params(cfg, jax.random.PRNGKey(0)),
+                           n_slots=2, max_len=32, page=8)
+    eng.tracer = tr = SpanTracer()
+    gov = Governor(recorder=GovernorTap(tr))
+    prompts = np.random.default_rng(0).integers(0, cfg.vocab, (3, 8)).astype(np.int32)
+    # the first arrival is late, so the meter books an idle phase before it
+    t0 = time.monotonic()
+    eng.serve([Request(prompt=p, max_new=3, arrival=0.05) for p in prompts],
+              governor=gov)
+    t1 = time.monotonic()
+    trace = tr.build()
+    assert validate_trace(trace, require_tracks=("ranks", "serve")) == []
+    ev = trace["traceEvents"]
+    spans = [e for e in ev if e["ph"] == "X" and e["pid"] == TRACK_PIDS["ranks"]]
+    serve = [e for e in ev if e["ph"] == "i" and e["pid"] == TRACK_PIDS["serve"]]
+    assert sorted(e["name"] for e in serve) == ["evict"] * 3 + ["join"] * 3
+    lo = min(e["ts"] for e in spans)
+    hi = max(e["ts"] + e["dur"] for e in spans)
+    # the last evict follows the last step's span by the step's readback
+    assert all(lo <= e["ts"] <= hi + 50e3 for e in serve)
+    assert t0 <= trace["otherData"]["monotonic_epoch_s"] <= t1
 
 
 # --------------------------------------------------------------------------
