@@ -254,14 +254,15 @@ def cache_shardings(mesh, cache: Any, *, serve_tp: bool = False) -> Any:
 
 
 # page-pool leaf rules: name -> KV-head dim index with the leading scan-stack
-# axis (if any) stripped.  Page arrays are (n_pages, page, Hkv, D) / scale
-# pages (n_pages, page, Hkv); recurrent-state leaves fall through to
+# axis (if any) stripped, or None.  Page arrays are (n_pages, page, Hkv, D);
+# scale pages are flat rows (n_pages, 1, page*Hkv) with no head dim of their
+# own, so they replicate; recurrent-state leaves fall through to
 # _CACHE_RULES (per-slot batch dim over the data axes).
 _PAGE_RULES = {
     "k_pages": 2,
     "v_pages": 2,
-    "k_scale_pages": 2,
-    "v_scale_pages": 2,
+    "k_scale_pages": None,
+    "v_scale_pages": None,
 }
 
 
@@ -271,7 +272,8 @@ def page_pool_shardings(mesh, blocks: Any, *, serve_tp: bool = True) -> Any:
     Page arrays replicate over the data axes — any decode slot must reach
     any physical page, so the pool cannot shard over requests — and with
     ``serve_tp`` split the KV-head dim over ``'model'``, matching the
-    tensor-parallel head split of ``serve_param_shardings``.  Recurrent
+    tensor-parallel head split of ``serve_param_shardings`` (the flat
+    scale rows have no head dim and replicate).  Recurrent
     per-slot state shards its slot (batch) dim over the data axes like the
     dense cache.
     """
@@ -283,7 +285,7 @@ def page_pool_shardings(mesh, blocks: Any, *, serve_tp: bool = True) -> Any:
         offset = 1 if stacked else 0
         entries: list = [None] * x.ndim
         if name in _PAGE_RULES:
-            if serve_tp:
+            if serve_tp and _PAGE_RULES[name] is not None:
                 entries[_PAGE_RULES[name] + offset] = "model"
         else:
             b_dim, tp_dim = _CACHE_RULES.get(name, (0, None))

@@ -75,11 +75,13 @@ def paged_attention_quant(q, k_pages, v_pages, k_scale_pages, v_scale_pages,
 
 @partial(jax.jit, static_argnames=("window",))
 def paged_attention_scatter(q, k_new, v_new, k_pages, v_pages, table, pos,
-                            page_idx, off, window: int = 0):
+                            page_idx, off, layer=None, window: int = 0):
     """Fused decode step (scatter prologue + paged attention, one
-    dispatch).  Returns ``(out, (k_pages, v_pages))``."""
+    dispatch) of layer ``layer`` of stacked (L,P,page,Hkv,D) pools, or of
+    one layer's pools when ``layer`` is None.  Returns ``(out, (k_pages,
+    v_pages))``."""
     return paged_attention_scatter_pallas(
-        q, k_new, v_new, k_pages, v_pages, table, pos, page_idx, off,
+        q, k_new, v_new, k_pages, v_pages, table, pos, page_idx, off, layer,
         window=window, interpret=_interpret(),
     )
 
@@ -88,12 +90,13 @@ def paged_attention_scatter(q, k_new, v_new, k_pages, v_pages, table, pos,
 def paged_attention_scatter_quant(q, k_new, v_new, k_scale_new, v_scale_new,
                                   k_pages, v_pages, k_scale_pages,
                                   v_scale_pages, table, pos, page_idx, off,
-                                  window: int = 0):
+                                  layer=None, window: int = 0):
     """Fused decode step over int8 pages; the prologue also lands the new
-    row's scales, the walk dequants in-flight.  Returns
+    row's scales, the walk dequants in-flight.  Pools and ``layer`` as
+    :func:`paged_attention_scatter`.  Returns
     ``(out, (k_pages, v_pages, k_scale_pages, v_scale_pages))``."""
     return paged_attention_scatter_pallas(
-        q, k_new, v_new, k_pages, v_pages, table, pos, page_idx, off,
+        q, k_new, v_new, k_pages, v_pages, table, pos, page_idx, off, layer,
         k_scale_new=k_scale_new, v_scale_new=v_scale_new,
         k_scale_pages=k_scale_pages, v_scale_pages=v_scale_pages,
         window=window, interpret=_interpret(),

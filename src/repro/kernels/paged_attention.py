@@ -25,9 +25,11 @@ exactly like ``flash_attention.py`` folds k-blocks.  Under
 ``kv_quant`` the pages are int8 with per-(token, head) float32 scales;
 a ``(P, page, Hkv)`` scale pool has too narrow a minor dim to slice, so
 the kernel sees it as ``(P, 1, page*Hkv)`` — one lane-dense row per
-page, laid out like the flat page tile's rows — and applies the K
-scales to the score columns and the V scales to the probabilities
-(dequant fused into the tile already on-chip, no dequantized cache).
+page, laid out like the flat page tile's rows, the form in which the
+serving pool stores its scale pages, so the view costs no relayout —
+and applies the K scales to the score columns and the V scales to the
+probabilities (dequant fused into the tile already on-chip, no
+dequantized cache).
 
 Causal masking needs no query position: decode queries sit at position
 ``pos[b]`` and every stored key at ``j*page + offset`` is valid iff it
@@ -51,6 +53,13 @@ which reads only slot b's pages — so the in-place store can never race
 another program's page reads, and the whole read-modify-attend step is
 one dispatch.  (Idle slots are parked on the scratch page, which every
 program's mask excludes, so even a torn scratch write is unobservable.)
+
+The fused form takes the pools of every scanned layer at once, stacked
+``(L, P, page, Hkv, D)``, and the layer as a fifth scalar-prefetch
+operand: the prologue writes ``pool[layer, page, off]`` and the walk
+reads ``pool[layer, pid]``, so the decode step's layer scan hands the
+kernel the whole stacked pool from its carry and no layer is ever sliced
+out of it or written back (the stacked arrays are the aliased operands).
 """
 from __future__ import annotations
 
@@ -67,26 +76,27 @@ NEG_INF = -1e30
 
 
 def _flat_scales(s_pages):
-    """(P, page, Hkv) scale pool -> (P, 1, page*Hkv): one lane-dense row
-    per page, the only shape in which a narrow-minor float32 page can be
-    DMA'd (Mosaic slices the lane dim in whole 128-lane tiles)."""
-    p, page, hkv = s_pages.shape
-    return s_pages.reshape(p, 1, page * hkv)
+    """([L,] P, page, Hkv) scale pool -> ([L,] P, 1, page*Hkv): one
+    lane-dense row per page, the only shape in which a narrow-minor float32
+    page can be DMA'd (Mosaic slices the lane dim in whole 128-lane tiles)."""
+    *lead, page, hkv = s_pages.shape
+    return s_pages.reshape(*lead, 1, page * hkv)
 
 
-def _page_walk(tbl_ref, pos_ref, b, q, pools, bufs, sems,
+def _page_walk(tbl_ref, pos_ref, b, q, pools, bufs, sems, lead=(),
                *, scale: float, window: int, page: int, n_pages: int,
                hkv: int, g: int):
     """Online-softmax walk over slot ``b``'s table row, two pages per
     iteration, every KV head at once.
 
     ``pools`` are the ANY-space (HBM) page pools: ``(k, v)`` or ``(k, v,
-    k_scale, v_scale)``.  Each iteration DMAs two whole pages (all heads)
-    of each pool into one half of a double-buffered VMEM scratch while
-    the other half is attended, so the next pair is in flight during the
-    current pair's matmuls.  A page tile ``(page, Hkv, D)`` is read as a
-    flat ``(page*Hkv, D)`` matrix: row ``t*Hkv + h`` is token ``t`` of
-    head ``h``.  The score tile is one ``(Hkv*G, 2*page*Hkv)`` MXU matmul
+    k_scale, v_scale)``; ``lead`` indexes them down to one layer's pages
+    (``(layer,)`` for stacked pools).  Each iteration DMAs two whole
+    pages (all heads) of each pool into one half of a double-buffered
+    VMEM scratch while the other half is attended, so the next pair is in
+    flight during the current pair's matmuls.  A page tile ``(page, Hkv,
+    D)`` is read as a flat ``(page*Hkv, D)`` matrix: row ``t*Hkv + h`` is
+    token ``t`` of head ``h``.  The score tile is one ``(Hkv*G, 2*page*Hkv)`` MXU matmul
     in which each query row keeps only its own head's columns (the rest
     are masked like future positions), so no per-head strided load is
     needed.  For odd ``n_pages`` the trailing phantom page re-loads the
@@ -102,7 +112,8 @@ def _page_walk(tbl_ref, pos_ref, b, q, pools, bufs, sems,
     def copies(jj, slot):
         j0 = 2 * jj
         pids = (tbl_ref[b, j0], tbl_ref[b, jnp.minimum(j0 + 1, n_pages - 1)])
-        return [pltpu.make_async_copy(pool.at[pid], buf.at[slot, i], sems.at[slot])
+        return [pltpu.make_async_copy(pool.at[(*lead, pid)], buf.at[slot, i],
+                                      sems.at[slot])
                 for i, pid in enumerate(pids) for pool, buf in zip(pools, bufs)]
 
     col = jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
@@ -154,11 +165,12 @@ def _page_walk(tbl_ref, pos_ref, b, q, pools, bufs, sems,
     return acc / jnp.maximum(l_f, 1e-20)
 
 
-def _walk_scratch(pools):
-    """Double-buffered VMEM page tiles (2 slots x 2 pages) for each pool,
-    plus DMA semaphores: one per walk slot, then one each for the
-    prologue's row writes and its scale-row read-modify-write."""
-    bufs = [pltpu.VMEM((2, 2) + a.shape[1:], a.dtype) for a in pools]
+def _walk_scratch(pools, n_lead: int = 1):
+    """Double-buffered VMEM page tiles (2 slots x 2 pages) for each pool
+    (whose first ``n_lead`` axes index a page), plus DMA semaphores: one
+    per walk slot, then one each for the prologue's row writes and its
+    scale-row read-modify-write."""
+    bufs = [pltpu.VMEM((2, 2) + a.shape[n_lead:], a.dtype) for a in pools]
     return bufs + [pltpu.SemaphoreType.DMA((4,))]
 
 
@@ -226,8 +238,8 @@ def paged_attention_pallas(
     return out.reshape(bsz, hkv, g, d)
 
 
-def _paged_step_kernel(tbl_ref, pos_ref, pidx_ref, offw_ref, q_ref, *rest,
-                       n_pools: int, **kw):
+def _paged_step_kernel(tbl_ref, pos_ref, pidx_ref, offw_ref, layer_ref, q_ref,
+                       *rest, n_pools: int, **kw):
     # rest = (*new_rows, *pool_inputs, o, *pool_outputs, *bufs, sems); the
     # pool outputs alias the pool inputs, so the body only ever touches
     # the output refs
@@ -237,6 +249,7 @@ def _paged_step_kernel(tbl_ref, pos_ref, pidx_ref, offw_ref, q_ref, *rest,
     bufs = rest[3 * n_pools + 1:4 * n_pools + 1]
     sems = rest[4 * n_pools + 1]
     b = pl.program_id(0)
+    lyr = layer_ref[0]
     pw = pidx_ref[b]
     ow = offw_ref[b]
     page, hkv = kw["page"], kw["hkv"]
@@ -247,7 +260,7 @@ def _paged_step_kernel(tbl_ref, pos_ref, pidx_ref, offw_ref, q_ref, *rest,
     # no cross-program hazard.  K/V rows are one (Hkv, D) tile each; a
     # scale row is a lane slice of its page's flat row, so it goes in by
     # read-modify-write of that row through the walk buffer.
-    rows = [pltpu.make_async_copy(news[i].at[0], pools[i].at[pw, ow], sems.at[2])
+    rows = [pltpu.make_async_copy(news[i].at[0], pools[i].at[lyr, pw, ow], sems.at[2])
             for i in range(2)]
     for c in rows:
         c.start()
@@ -256,18 +269,18 @@ def _paged_step_kernel(tbl_ref, pos_ref, pidx_ref, offw_ref, q_ref, *rest,
         mine = lane // hkv == ow
         for i in (2, 3):
             tile = bufs[i].at[0, 0]
-            c = pltpu.make_async_copy(pools[i].at[pw], tile, sems.at[3])
+            c = pltpu.make_async_copy(pools[i].at[lyr, pw], tile, sems.at[3])
             c.start()
             c.wait()
             tile[...] = jnp.where(mine, news[i][0], tile[...])
-            c = pltpu.make_async_copy(tile, pools[i].at[pw], sems.at[3])
+            c = pltpu.make_async_copy(tile, pools[i].at[lyr, pw], sems.at[3])
             c.start()
             c.wait()
     for c in rows:
         c.wait()
 
     q = q_ref[0].astype(jnp.float32)                   # (Hkv*G, D)
-    out = _page_walk(tbl_ref, pos_ref, b, q, pools, bufs, sems, **kw)
+    out = _page_walk(tbl_ref, pos_ref, b, q, pools, bufs, sems, (lyr,), **kw)
     o_ref[0] = out.astype(o_ref.dtype)
 
 
@@ -281,6 +294,7 @@ def paged_attention_scatter_pallas(
     pos: jnp.ndarray,
     page_idx: jnp.ndarray,
     off: jnp.ndarray,
+    layer: Optional[jnp.ndarray] = None,
     *,
     k_scale_new: Optional[jnp.ndarray] = None,
     v_scale_new: Optional[jnp.ndarray] = None,
@@ -289,20 +303,38 @@ def paged_attention_scatter_pallas(
     window: int = 0,
     interpret: bool = False,
 ) -> Tuple[jnp.ndarray, Tuple[jnp.ndarray, ...]]:
-    """Fused decode step: scatter the new K/V row, then attend — one
-    dispatch.  Bit-identical to ``paged_scatter_pallas`` followed by
-    ``paged_attention_pallas`` (tier-1 asserted).
+    """Fused decode step of one layer: scatter the new K/V row, then
+    attend — one dispatch.  Bit-identical to ``paged_scatter_pallas``
+    followed by ``paged_attention_pallas`` on that layer's pages, with
+    every other layer's pages untouched (tier-1 asserted).
 
+    k_pages/v_pages: (L, P, page, Hkv, D) the pools of every scanned layer
+    (int8 when quantized), k/v_scale_pages: their float32 scales, (L, P,
+    page, Hkv) or the serving pool's flat rows (L, P, 1, page*Hkv), which
+    the kernel reads with no relayout, and ``layer`` the int32 scalar
+    index of the layer this step attends; with ``layer=None`` the pools
+    are one layer's, without the L axis, given a unit layer axis by
+    reshape.
     k_new/v_new: (B, Hkv, D) the new token's K/V rows (pool dtype —
     already quantized when the pool is int8); k/v_scale_new: (B, Hkv)
     their dequant scales; page_idx/off: (B,) int32 write destinations
     (idle slots point at the scratch page).  Other shapes as
-    :func:`paged_attention_pallas`.  Returns ``(out, updated_pools)``.
+    :func:`paged_attention_pallas`.  Returns ``(out, updated_pools)``,
+    the pools in the shapes given.  The pools are aliased to the
+    updated ones: under a caller's ``jit`` that donates them (the serving
+    engine's decode step), the row lands in place and the caller's input
+    pools are invalidated.
     """
     bsz, hkv, g, d = q.shape
-    page = k_pages.shape[1]
-    m = table.shape[1]
     quant = k_scale_pages is not None
+    one_layer = layer is None
+    if one_layer:                              # a unit layer axis, by reshape
+        layer = 0
+        k_pages, v_pages = k_pages[None], v_pages[None]
+        if quant:
+            k_scale_pages, v_scale_pages = k_scale_pages[None], v_scale_pages[None]
+    page = k_pages.shape[2]
+    m = table.shape[1]
 
     q_spec = pl.BlockSpec((1, hkv * g, d), lambda b, *_: (b, 0, 0))
     row_spec = pl.BlockSpec((1, hkv, d), lambda b, *_: (b, 0, 0))
@@ -319,13 +351,13 @@ def paged_attention_scatter_pallas(
         new_specs += [srow_spec, srow_spec]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,                 # table, pos, page_idx, off
+        num_scalar_prefetch=5,                 # table, pos, page_idx, off, layer
         grid=(bsz,),
         in_specs=[q_spec] + new_specs + [any_spec] * len(pools),
         out_specs=[q_spec] + [any_spec] * len(pools),
-        scratch_shapes=_walk_scratch(pools),
+        scratch_shapes=_walk_scratch(pools, n_lead=2),
     )
-    base = 4 + 1 + len(news)                   # scalar-prefetch + q + rows
+    base = 5 + 1 + len(news)                   # scalar-prefetch + q + rows
     out = pl.pallas_call(
         functools.partial(_paged_step_kernel, n_pools=len(pools),
                           scale=1.0 / math.sqrt(d), window=window, page=page,
@@ -336,11 +368,14 @@ def paged_attention_scatter_pallas(
         input_output_aliases={base + i: 1 + i for i in range(len(pools))},
         interpret=interpret,
         name="paged_attention_scatter",
-    )(table, pos, page_idx, off, q.reshape(bsz, hkv * g, d), *news, *pools)
+    )(table, pos, page_idx, off, jnp.reshape(layer, (1,)).astype(jnp.int32),
+      q.reshape(bsz, hkv * g, d), *news, *pools)
     new_pools = list(out[1:])
     if quant:
         new_pools[2:] = [a.reshape(s.shape) for a, s in
                          zip(new_pools[2:], (k_scale_pages, v_scale_pages))]
+    if one_layer:
+        new_pools = [a.reshape(a.shape[1:]) for a in new_pools]
     return out[0].reshape(bsz, hkv, g, d), tuple(new_pools)
 
 

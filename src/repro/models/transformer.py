@@ -274,8 +274,8 @@ def _block_prefill(cfg, kind, p, x, positions, bc):
 
 def _block_decode(cfg, kind, p, x, pos, bc, attn_fn=None):
     """One block's single-token step.  ``attn_fn(p_attn, h, bc) -> (y, bc)``
-    overrides the dense-cache attention (the paged serving engine passes a
-    page-table closure); everything else is shared."""
+    overrides the dense-cache attention (the paged serving engine's walker
+    passes a page-table closure); everything else is shared."""
     p = L.compute_weights(cfg, p)
     if kind == "attn":
         h = L.apply_norm(cfg, p["ln1"], x)
@@ -349,13 +349,12 @@ def prefill(cfg, params, batch, cache) -> Tuple[jnp.ndarray, Params]:
     return logits, new_cache
 
 
-def decode_step(cfg, params, token, pos, cache, *, attn_fn=None) -> Tuple[jnp.ndarray, Params]:
-    """One decode step.  token: (B,) int32; pos: scalar int32 position
-    (or (B,) per-request positions when ``attn_fn`` handles them).
-
-    ``cache`` may be the dense per-slot cache from :func:`init_cache`, or
-    any tree with the same stack/rem block structure whose attention
-    entries are consumed by ``attn_fn`` (see ``repro.serve.engine``)."""
+def decode_step(cfg, params, token, pos, cache) -> Tuple[jnp.ndarray, Params]:
+    """One decode step over the dense per-slot cache from
+    :func:`init_cache`, which flows through the layer scan's ``xs`` and
+    ``ys``.  token: (B,) int32; pos: scalar int32 position.  (The paged
+    pool has its own walker, ``serve.engine.make_paged_decode_step``,
+    which carries the pool instead.)"""
     dtype = L.dtype_of(cfg.compute_dtype)
     x = jnp.take(params["embed"], token[:, None], axis=0).astype(dtype)
     x = x * math.sqrt(cfg.d_model)
@@ -363,9 +362,7 @@ def decode_step(cfg, params, token, pos, cache, *, attn_fn=None) -> Tuple[jnp.nd
     def decode_period(x, pp, pc):
         new_pc = {}
         for j, kind in enumerate(cfg.pattern):
-            x, new_pc[str(j)] = _block_decode(
-                cfg, kind, pp[str(j)], x, pos, pc[str(j)], attn_fn
-            )
+            x, new_pc[str(j)] = _block_decode(cfg, kind, pp[str(j)], x, pos, pc[str(j)])
         return x, new_pc
 
     if cfg.scan_layers:
@@ -389,9 +386,7 @@ def decode_step(cfg, params, token, pos, cache, *, attn_fn=None) -> Tuple[jnp.nd
     if rem_kinds:
         new_cache["rem"] = {}
         for j, kind in enumerate(rem_kinds):
-            x, bc = _block_decode(
-                cfg, kind, params["rem"][str(j)], x, pos, cache["rem"][str(j)], attn_fn
-            )
+            x, bc = _block_decode(cfg, kind, params["rem"][str(j)], x, pos, cache["rem"][str(j)])
             new_cache["rem"][str(j)] = bc
     x = L.apply_norm(cfg, params["final_norm"], x)
     logits = logits_fn(cfg, params, x)[:, 0]

@@ -27,7 +27,10 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
+from repro.models import layers as L
+from repro.models.transformer import _block_decode, _embed_inputs, logits_fn
 from repro.models.transformer import decode_step as _decode
 from repro.models.transformer import init_cache, stack_layout
 from repro.models.transformer import prefill as _prefill
@@ -108,10 +111,21 @@ def make_paged_decode_step(cfg, attn_kernel: str = "xla",
                            fused_sample: bool = False) -> Callable:
     """decode(params, token (B,), pos (B,), table (B,M), blocks) -> (out, blocks).
 
-    Reuses the stock ``transformer.decode_step`` walker (scan/rem stack,
-    MoE dropless decode, SSM/RG-LRU state) and swaps only the attention:
-    a closure over the page table routes it through the paged pool, via
-    the XLA reference path or the Pallas paged kernel (``attn_kernel``).
+    The paged pool is state that every step updates in place.  The step
+    walks the scanned layer stack with the pool's stacked ``blocks["stack"]``
+    entries and the layer index in the ``lax.scan`` carry (the weights are
+    the scanned ``xs``): each layer's attention gets the whole stacked
+    block and the index, and returns the stacked block with one row per
+    slot written into that layer's pages (``kvcache.paged_attention_decode``,
+    via the XLA reference path or the Pallas paged kernel, ``attn_kernel``);
+    recurrent-state blocks (SSM / RG-LRU) are read and written at ``[l]``.
+    So no layer of the pool is sliced out or stacked back, and a caller
+    that donates ``blocks`` (``ContinuousEngine`` does) gets the same
+    buffers back, written in place, and its input pool is invalidated.
+    The unscanned remainder (``rem``) keeps its per-layer blocks.  The
+    layer body is the stock ``transformer._block_decode``; the dense cache
+    keeps ``transformer.decode_step``, whose per-call cache flows through
+    the scan's ``xs``/``ys``.
 
     With ``fused_sample`` the greedy argmax runs inside the same jitted
     dispatch and ``out`` is the sampled ``(B,)`` int32 tokens — the step
@@ -119,17 +133,53 @@ def make_paged_decode_step(cfg, attn_kernel: str = "xla",
     plus a second argmax dispatch.  Callers that need logits (sampling
     with temperature) keep the unfused step.
     """
+    n_full, rem_kinds = stack_layout(cfg)
 
     def step(params, token, pos, table, blocks):
-        def paged_attn(p_attn, h, bc):
-            return paged_attention_decode(
-                cfg, p_attn, h, pos, table, bc, kernel=attn_kernel
-            )
+        def block(kind, p, x, bc, layer):
+            if kind == "attn":
+                def paged_attn(p_attn, h, bc):
+                    return paged_attention_decode(cfg, p_attn, h, pos, table, bc,
+                                                  kernel=attn_kernel, layer=layer)
 
-        logits, blocks = _decode(cfg, params, token, pos, blocks, attn_fn=paged_attn)
+                return _block_decode(cfg, kind, p, x, pos, bc, paged_attn)
+            if layer is None:
+                return _block_decode(cfg, kind, p, x, pos, bc)
+            state = jax.tree.map(lambda a: a[layer], bc)
+            x, state = _block_decode(cfg, kind, p, x, pos, state)
+            return x, jax.tree.map(lambda a, s: a.at[layer].set(s), bc, state)
+
+        def period(x, stack, pp, layer):
+            stack = dict(stack)
+            for j, kind in enumerate(cfg.pattern):
+                x, stack[str(j)] = block(kind, pp[str(j)], x, stack[str(j)], layer)
+            return x, stack
+
+        x = _embed_inputs(cfg, params, {"tokens": token[:, None]})
+        stack = blocks["stack"]
+        if cfg.scan_layers:
+            def body(carry, pp):
+                x, stack, layer = carry
+                x, stack = period(x, stack, pp, layer)
+                return (x, stack, layer + 1), None
+
+            (x, stack, _), _ = lax.scan(body, (x, stack, jnp.int32(0)), params["stack"])
+        else:
+            for i in range(n_full):
+                pp = jax.tree.map(lambda a: a[i], params["stack"])
+                x, stack = period(x, stack, pp, i)
+        new_blocks = {"stack": stack}
+        if rem_kinds:
+            new_blocks["rem"] = {}
+            for j, kind in enumerate(rem_kinds):
+                x, new_blocks["rem"][str(j)] = block(
+                    kind, params["rem"][str(j)], x, blocks["rem"][str(j)], None
+                )
+        x = L.apply_norm(cfg, params["final_norm"], x)
+        logits = logits_fn(cfg, params, x)[:, 0]
         if fused_sample:
-            return jnp.argmax(logits, axis=-1).astype(jnp.int32), blocks
-        return logits, blocks
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32), new_blocks
+        return logits, new_blocks
 
     return step
 
@@ -138,7 +188,10 @@ def make_join_step(cfg) -> Callable:
     """join(blocks, prefill_cache, page_ids (n_used,), slot) -> blocks.
 
     Scatters a batch-1 prefill cache into the pool: attention K/V into the
-    slot's freshly allocated pages, recurrent state into the slot's row.
+    slot's freshly allocated pages of every layer of the stacked pool,
+    recurrent state into the slot's row.  ``ContinuousEngine`` donates
+    ``blocks``, so the scatter lands in place and the caller's input pool
+    is invalidated.
     """
 
     def join(blocks, cache, page_ids, slot):
@@ -189,6 +242,13 @@ class ContinuousEngine:
     fused scatter epilogue; greedy decoding additionally samples inside
     the decode dispatch).  Both are token-for-token identical to the
     dense engine (tier-1 asserted).
+
+    Every program that writes the pool (decode, join, clone) donates it:
+    ``pool.blocks`` is replaced by each call's output, written in place.
+    ``pool_copied`` counts the compiled pool-writing programs (one per
+    decode table width, join length, and the clone) whose donated pool
+    survived their first call, i.e. that wrote a copy; it is checked on
+    a program's first call only, and should read 0.
     """
 
     cfg: Any
@@ -220,9 +280,11 @@ class ContinuousEngine:
         self._prefill = jax.jit(partial(_prefill, self.cfg))
         self._decode = jax.jit(make_paged_decode_step(
             self.cfg, self.attn_kernel, self._fused_sample
-        ))
-        self._join = jax.jit(make_join_step(self.cfg))
-        self._clone = jax.jit(make_clone_pages(self.cfg))
+        ), donate_argnums=4)
+        self._join = jax.jit(make_join_step(self.cfg), donate_argnums=0)
+        self._clone = jax.jit(make_clone_pages(self.cfg), donate_argnums=0)
+        self.pool_copied = 0
+        self._pool_programs: set = set()     # programs whose donation was checked
         m = self.pool.max_pages_per_req
         self._table = np.full((self.n_slots, m), SCRATCH_PAGE, np.int32)
         self._lengths = np.zeros((self.n_slots,), np.int32)
@@ -274,9 +336,11 @@ class ContinuousEngine:
         slot = req.slot
         self._table[slot] = SCRATCH_PAGE
         self._table[slot, :n_used] = req.pages
+        old = self.pool.blocks
         self.pool.blocks = self._join(
-            self.pool.blocks, cache, jnp.asarray(req.pages, jnp.int32), jnp.int32(slot)
+            old, cache, jnp.asarray(req.pages, jnp.int32), jnp.int32(slot)
         )
+        self._check_donated(("join", n_used), old)
         tok = int(self._select_one(logits[0], req))
         req.out.append(tok)
         self._lengths[slot] = total
@@ -295,9 +359,11 @@ class ContinuousEngine:
             # place, so it writes into a private clone while other referents
             # keep reading the shared original
             (pid,) = self.pool.alloc(req.rid, 1)
+            old = self.pool.blocks
             self.pool.blocks = self._clone(
-                self.pool.blocks, jnp.int32(m.partial_page), jnp.int32(pid)
+                old, jnp.int32(m.partial_page), jnp.int32(pid)
             )
+            self._check_donated(("clone",), old)
             pages.append(pid)
         req.pages = pages
         slot = req.slot
@@ -308,6 +374,16 @@ class ContinuousEngine:
         rest = prompt[m.n_tokens + 1:]
         if len(rest):
             self._forced[slot] = collections.deque(int(t) for t in rest)
+
+    def _check_donated(self, program, old_blocks) -> None:
+        """On ``program``'s first call, count it in ``pool_copied`` if the
+        pool it was handed is still alive: it was not donated, so the
+        program wrote its output pool beside it."""
+        if program in self._pool_programs:
+            return
+        self._pool_programs.add(program)
+        if not all(leaf.is_deleted() for leaf in jax.tree.leaves(old_blocks)):
+            self.pool_copied += 1
 
     def _select_one(self, logits, req: Request) -> int:
         if self.temperature <= 0.0 or req.key is None:
@@ -481,12 +557,12 @@ class EngineSession:
     def admit(self, now: Optional[float] = None) -> List[Request]:
         """Join every arrived request that fits; returns the joins.
 
-        Under a recording profiler: the span ``engine.admit``, and in it one
-        ``engine.prefill`` per join with the request's ``rid``,
-        ``prompt_len`` and ``wait_us`` (from its arrival to the join's
-        start, on the session's clock)."""
+        Under a recording profiler: the span ``engine.admit`` with the
+        engine's ``pool_copied``, and in it one ``engine.prefill`` per join
+        with the request's ``rid``, ``prompt_len`` and ``wait_us`` (from
+        its arrival to the join's start, on the session's clock)."""
         eng = self.engine
-        with span("engine.admit"):
+        with span("engine.admit", pool_copied=eng.pool_copied):
             joins = self.sched.admit(self.now() if now is None else now)
             for req in joins:
                 with (span("engine.prefill", rid=req.rid, prompt_len=len(req.prompt),
@@ -551,19 +627,20 @@ class EngineSession:
                 inputs = (jnp.asarray(eng._tokens), jnp.asarray(eng._lengths),
                           jnp.asarray(eng._table[:, :m_live]))
             with span("engine.dispatch"):
-                out, blocks = eng._decode(eng.params, *inputs, eng.pool.blocks)
+                old = eng.pool.blocks
+                out, eng.pool.blocks = eng._decode(eng.params, *inputs, old)
+                eng._check_donated(("decode", m_live), old)
             with span("engine.wait"):
                 out = jax.block_until_ready(out)
             t1 = time.monotonic()
             with span("engine.commit"):
-                self._commit(out, blocks, t0, t1)
+                self._commit(out, t0, t1)
         self.steps += 1
 
-    def _commit(self, out, blocks, t0: float, t1: float) -> None:
+    def _commit(self, out, t0: float, t1: float) -> None:
         """A finished decode step's tokens, one per active slot."""
         eng = self.engine
         sched = self.sched
-        eng.pool.blocks = blocks
         if self.meter is not None:
             self.meter.step(t0, t1, sched.n_active, eng.n_slots)
         if eng._fused_sample:

@@ -8,7 +8,10 @@ This module provides exactly that:
 * :class:`PagedKVPool` — one physical pool of fixed-size pages per
   attention layer (``k_pages``/``v_pages``: ``(n_pages, page, Hkv, D)``,
   int8 + per-(token, head) scale pages when ``cfg.kv_quant``, reusing the
-  ``_kv_quantize`` path from :mod:`repro.models.layers`), a host-side
+  ``_kv_quantize`` path from :mod:`repro.models.layers`; a scale page is
+  stored as one flat row ``(1, page*Hkv)``, entry ``t*Hkv + h`` for token
+  ``t`` of head ``h``, the form the paged kernel reads, so no step
+  relays the scale pool out), a host-side
   free-list allocator with *reservations* (admission control books the
   worst-case page need up front, physical pages are allocated lazily, so
   a lazily-grown request can never hit an empty free list), and
@@ -32,6 +35,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.models import layers as L
 from repro.models import rglru as R
@@ -68,8 +72,8 @@ def _attn_page_block(cfg, num_pages: int, page: int, dtype) -> Params:
         "v_pages": jnp.zeros((num_pages, page, hkv, hd), kv_dtype),
     }
     if cfg.kv_quant:
-        block["k_scale_pages"] = jnp.zeros((num_pages, page, hkv), jnp.float32)
-        block["v_scale_pages"] = jnp.zeros((num_pages, page, hkv), jnp.float32)
+        block["k_scale_pages"] = jnp.zeros((num_pages, 1, page * hkv), jnp.float32)
+        block["v_scale_pages"] = jnp.zeros((num_pages, 1, page * hkv), jnp.float32)
     return block
 
 
@@ -109,12 +113,16 @@ def init_pool_blocks(cfg, num_pages: int, page: int, n_slots: int) -> Params:
 # paged decode attention
 # --------------------------------------------------------------------------
 
-def paged_attention_decode(cfg, p, x, pos, table, block, kernel: str = "xla"):
+def paged_attention_decode(cfg, p, x, pos, table, block, kernel: str = "xla",
+                           layer=None):
     """Single-token attention over paged KV.
 
     x: (B,1,d); pos: (B,) int32 write positions (the new token's absolute
     position per request); table: (B, M) int32 page table (0 = scratch);
-    block: one attention page block.  Returns (out (B,1,d), new block).
+    block: one attention page block, or with ``layer`` (an int32 scalar)
+    the stacked block of every scanned layer, whose leaves lead with the
+    layer axis, of which layer ``layer`` is read and written and every
+    other left as it is.  Returns (out (B,1,d), new block).
 
     ``kernel`` selects the hot path: ``"xla"`` scatters with
     ``.at[].set()`` and gathers a contiguous ``(B, M*page, Hkv, D)`` view
@@ -127,8 +135,9 @@ def paged_attention_decode(cfg, p, x, pos, table, block, kernel: str = "xla"):
     *stored* pages are bit-identical.
     """
     b = x.shape[0]
-    page = block["k_pages"].shape[1]
+    page = block["k_pages"].shape[-3]
     m = table.shape[1]
+    lead = () if layer is None else (layer,)
     q, k, v = _project_qkv(cfg, p, x)                          # (B,1,H*,D)
     q = rope_at(q, pos, cfg.rope_theta)
     k = rope_at(k, pos, cfg.rope_theta)
@@ -152,14 +161,14 @@ def paged_attention_decode(cfg, p, x, pos, table, block, kernel: str = "xla"):
                 qg, k[:, 0], v[:, 0], k_sc[:, 0], v_sc[:, 0],
                 block["k_pages"], block["v_pages"],
                 block["k_scale_pages"], block["v_scale_pages"],
-                table, pos, page_idx, off, window=window,
+                table, pos, page_idx, off, layer, window=window,
             )
             new_block.update(k_pages=kp, v_pages=vp,
                              k_scale_pages=ksp, v_scale_pages=vsp)
         else:
             out, (kp, vp) = pallas_ops.paged_attention_scatter(
                 qg, k[:, 0], v[:, 0], block["k_pages"], block["v_pages"],
-                table, pos, page_idx, off, window=window,
+                table, pos, page_idx, off, layer, window=window,
             )
             new_block.update(k_pages=kp, v_pages=vp)
         out = out.astype(x.dtype).reshape(b, 1, cfg.n_heads * hd) @ p["wo"]
@@ -167,19 +176,23 @@ def paged_attention_decode(cfg, p, x, pos, table, block, kernel: str = "xla"):
     if kernel != "xla":
         raise ValueError(f"unknown attention kernel {kernel!r}")
 
+    # one scatter and one gather per leaf, each indexed by the layer too:
+    # no layer of a stacked pool is sliced out or written back whole
     if cfg.kv_quant:
-        new_block["k_scale_pages"] = block["k_scale_pages"].at[page_idx, off].set(k_sc[:, 0])
-        new_block["v_scale_pages"] = block["v_scale_pages"].at[page_idx, off].set(v_sc[:, 0])
-    new_block["k_pages"] = block["k_pages"].at[page_idx, off].set(k[:, 0])
-    new_block["v_pages"] = block["v_pages"].at[page_idx, off].set(v[:, 0])
+        lanes = off[:, None] * hkv + jnp.arange(hkv)           # (B, Hkv) in the flat row
+        at = lead + (page_idx[:, None], 0, lanes)
+        new_block["k_scale_pages"] = block["k_scale_pages"].at[at].set(k_sc[:, 0])
+        new_block["v_scale_pages"] = block["v_scale_pages"].at[at].set(v_sc[:, 0])
+    new_block["k_pages"] = block["k_pages"].at[lead + (page_idx, off)].set(k[:, 0])
+    new_block["v_pages"] = block["v_pages"].at[lead + (page_idx, off)].set(v[:, 0])
 
     # gather this batch's logical KV views: (B, M, page, H, D) -> (B, T, H, D)
     t = m * page
-    ck = new_block["k_pages"][table].reshape(b, t, hkv, hd)
-    cv = new_block["v_pages"][table].reshape(b, t, hkv, hd)
+    ck = new_block["k_pages"][lead + (table,)].reshape(b, t, hkv, hd)
+    cv = new_block["v_pages"][lead + (table,)].reshape(b, t, hkv, hd)
     if cfg.kv_quant:
-        k_sc = new_block["k_scale_pages"][table].reshape(b, t, hkv)
-        v_sc = new_block["v_scale_pages"][table].reshape(b, t, hkv)
+        k_sc = new_block["k_scale_pages"][lead + (table,)].reshape(b, t, hkv)
+        v_sc = new_block["v_scale_pages"][lead + (table,)].reshape(b, t, hkv)
         ck = _kv_dequantize(ck, k_sc, x.dtype)
         cv = _kv_dequantize(cv, v_sc, x.dtype)
 
@@ -208,7 +221,10 @@ def make_clone_pages(cfg) -> "Any":
     *partially filled* cached page writes into fresh storage while every
     other referent keeps reading the original.  Recurrent-state blocks are
     per-slot, not paged, so only attention leaves participate (the prefix
-    cache is gated to attention-only archs anyway).
+    cache is gated to attention-only archs anyway).  Stacked leaves copy
+    the page in every layer at once.  ``ContinuousEngine`` donates
+    ``blocks``: one page is written in place and the caller's input pool
+    is invalidated.
     """
 
     page_leaves = ("k_pages", "v_pages", "k_scale_pages", "v_scale_pages")
@@ -252,25 +268,32 @@ def scatter_prefill_attn(block, cache_block, page_ids, *, stacked: bool):
     cache_block leaves come from ``transformer.prefill`` with batch 1 and
     a linear layout of ``n_used * page`` positions; ``page_ids``:
     (n_used,) int32 physical destinations.  ``stacked`` marks entries
-    under the scan layer axis (leaves lead with n_full).
+    under the scan layer axis (leaves lead with n_full).  Each prefill
+    leaf is cut into pages of the pool leaf's own page shape (a scale
+    page's flat row included), and each page lands in every layer by one
+    ``dynamic_update_slice``: in place in a donated pool on every backend,
+    where a scatter along the axis after the layer axis makes XLA lay the
+    whole pool out again on some (CPU; the scale rows on a TPU).
     """
-    page = block["k_pages"].shape[-3]
     n_used = page_ids.shape[0]
-    new = dict(block)
+    axis = 1 if stacked else 0
     pairs = [("k", "k_pages"), ("v", "v_pages")]
     if "k_scale_pages" in block:
         pairs += [("k_scale", "k_scale_pages"), ("v_scale", "v_scale_pages")]
+    chunks = {}
     for name, pname in pairs:
-        if stacked:
-            leaf = cache_block[name][:, 0]                     # (n_full, Lpad, ...)
-            chunks = leaf.reshape(leaf.shape[0], n_used, page, *leaf.shape[2:])
-            new[pname] = block[pname].at[:, page_ids].set(
-                chunks.astype(block[pname].dtype)
-            )
-        else:
-            leaf = cache_block[name][0]                        # (Lpad, ...)
-            chunks = leaf.reshape(n_used, page, *leaf.shape[1:])
-            new[pname] = block[pname].at[page_ids].set(chunks.astype(block[pname].dtype))
+        pool = block[pname]
+        leaf = cache_block[name][:, 0] if stacked else cache_block[name][0]
+        shape = pool.shape[:axis] + (n_used,) + pool.shape[axis + 1:]
+        chunks[pname] = leaf.reshape(shape).astype(pool.dtype)
+
+    def put(i, pools):
+        return {k: lax.dynamic_update_slice_in_dim(
+                    p, lax.dynamic_slice_in_dim(chunks[k], i, 1, axis), page_ids[i], axis)
+                for k, p in pools.items()}
+
+    new = dict(block)
+    new.update(lax.fori_loop(0, n_used, put, {k: block[k] for k in chunks}))
     return new
 
 

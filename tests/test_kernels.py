@@ -248,6 +248,51 @@ def test_paged_attention_scatter_fuses_bit_equal(quant, window):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
+@pytest.mark.parametrize("quant,layer", [(False, 0), (False, 2), (True, 1)])
+def test_paged_attention_scatter_indexes_the_layer(quant, layer):
+    """On a stacked pool the layer-indexed fused kernel equals the
+    per-layer kernel on layer ``layer`` (output and that layer's pages),
+    and leaves every other layer's pages bit-for-bit as they were.  The
+    int8 scale pools come in the serving pool's flat-row form."""
+    n_layers, b, hkv, g, d, page, m = 3, 3, 2, 2, 32, 8, 4
+    n_pages = b * m + 2
+    cases = [_paged_case(b, hkv, g, d, page, m, n_pages, quant) for _ in range(n_layers)]
+    q, _, _, table, pos, _, _ = cases[layer]
+    page_idx = table[jnp.arange(b), pos // page]
+    off = pos % page
+    stack = lambda i: jnp.stack([c[i] for c in cases])
+    kp, vp = stack(1), stack(2)
+    if quant:
+        flat = lambda a: a.reshape(n_layers, n_pages, 1, page * hkv)
+        ks, vs = flat(stack(5)), flat(stack(6))
+        k_new = jnp.asarray(RNG.integers(-127, 128, (b, hkv, d)), jnp.int8)
+        v_new = jnp.asarray(RNG.integers(-127, 128, (b, hkv, d)), jnp.int8)
+        ks_new = jnp.asarray(RNG.uniform(1e-3, 0.1, (b, hkv)), jnp.float32)
+        vs_new = jnp.asarray(RNG.uniform(1e-3, 0.1, (b, hkv)), jnp.float32)
+        before = (kp, vp, ks, vs)
+        want_out, want = ops.paged_attention_scatter_quant(
+            q, k_new, v_new, ks_new, vs_new, kp[layer], vp[layer],
+            cases[layer][5], cases[layer][6], table, pos, page_idx, off)
+        want = want[:2] + tuple(a.reshape(n_pages, 1, page * hkv) for a in want[2:])
+        got_out, got = ops.paged_attention_scatter_quant(
+            q, k_new, v_new, ks_new, vs_new, kp, vp, ks, vs,
+            table, pos, page_idx, off, jnp.int32(layer))
+    else:
+        k_new = jnp.asarray(RNG.normal(0, 1, (b, hkv, d)), jnp.float32)
+        v_new = jnp.asarray(RNG.normal(0, 1, (b, hkv, d)), jnp.float32)
+        before = (kp, vp)
+        want_out, want = ops.paged_attention_scatter(
+            q, k_new, v_new, kp[layer], vp[layer], table, pos, page_idx, off)
+        got_out, got = ops.paged_attention_scatter(
+            q, k_new, v_new, kp, vp, table, pos, page_idx, off, jnp.int32(layer))
+    np.testing.assert_array_equal(np.asarray(got_out), np.asarray(want_out))
+    others = [i for i in range(n_layers) if i != layer]
+    for g_pool, w_layer, b_pool in zip(got, want, before):
+        assert g_pool.shape == b_pool.shape and g_pool.dtype == b_pool.dtype
+        np.testing.assert_array_equal(np.asarray(g_pool[layer]), np.asarray(w_layer))
+        np.testing.assert_array_equal(np.asarray(g_pool)[others], np.asarray(b_pool)[others])
+
+
 def test_interpret_follows_platform(monkeypatch):
     """Interpret mode on a CPU backend, Mosaic on any other — decided by
     the platform alone, with no environment override."""

@@ -1,8 +1,10 @@
 """Continuous-batching subsystem: paged pool, scheduler, slack bridge, SLO."""
 import dataclasses
+import re
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -10,6 +12,7 @@ from repro.configs import get_config, reduced
 from repro.core.governor import Governor
 from repro.models import init_params
 from repro.models.inputs import make_batch
+from repro.models.transformer import init_cache
 from repro.serve import (
     ContinuousEngine,
     PagedKVPool,
@@ -337,3 +340,108 @@ def test_pallas_fused_sample_only_for_greedy(rng_key):
     np.testing.assert_array_equal(s1, s2)
     with pytest.raises(ValueError, match="attn_kernel"):
         ContinuousEngine(cfg, params, attn_kernel="mosaic")
+
+
+# --------------------------------------------------------------------------
+# the pool is updated in place: donated, carried through the layer scan
+# --------------------------------------------------------------------------
+
+_HLO_OP = re.compile(r"^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* ([\w-]+)\((.*)")
+
+
+def _pool_copies(hlo: str, blocks) -> list:
+    """The instructions of a compiled module that copy the pool: a
+    ``copy`` or ``dynamic-slice`` whose result is a whole pool leaf or one
+    layer of it, or a ``dynamic-update-slice`` that writes a whole layer."""
+    layer, whole = set(), set()
+    for a in jax.tree.leaves(blocks):
+        whole.add(a.shape)
+        layer |= {a.shape[1:], (1,) + a.shape[1:]}
+    shapes, ops = {}, []
+    for line in hlo.splitlines():
+        m = _HLO_OP.match(line)
+        if m:
+            shapes[m[1]] = tuple(int(x) for x in m[2].split(",") if x)
+            ops.append((m[1], m[3], re.findall(r"%([\w.-]+)", m[4])))
+    bad = []
+    for name, op, operands in ops:
+        if op in ("copy", "dynamic-slice") and shapes[name] in whole | layer:
+            bad.append(name)
+        if (op == "dynamic-update-slice" and len(operands) > 1
+                and shapes.get(operands[1]) in layer):
+            bad.append(name)
+    return bad
+
+
+@pytest.mark.parametrize("program", ["decode", "join", "clone"])
+def test_pool_writing_programs_update_the_pool_in_place(rng_key, program):
+    """The compiled decode step, join and clone alias the donated pool to
+    their output and never slice out, write back or copy a layer of it."""
+    cfg = reduced(get_config("llama3.2-1b"))
+    params = init_params(cfg, rng_key)
+    eng = ContinuousEngine(cfg, params, n_slots=2, max_len=32, page=8)
+    blocks = eng.pool.blocks
+    zeros = jnp.zeros((2,), jnp.int32)
+    if program == "decode":
+        lowered = eng._decode.lower(params, zeros, zeros,
+                                    jnp.zeros((2, 2), jnp.int32), blocks)
+    elif program == "join":
+        lowered = eng._join.lower(blocks, init_cache(cfg, 1, 16), zeros, jnp.int32(0))
+    else:
+        lowered = eng._clone.lower(blocks, jnp.int32(1), jnp.int32(2))
+    compiled = lowered.compile()
+    assert _pool_copies(compiled.as_text(), blocks) == []
+    pool_bytes = sum(a.nbytes for a in jax.tree.leaves(blocks))
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
+
+
+def test_pool_copy_finder_sees_a_per_layer_walk(rng_key):
+    """The finder above flags what a walk with the pool in the scan's
+    ``xs``/``ys`` compiles to: a layer sliced out, written and stacked back."""
+    cfg = reduced(get_config("llama3.2-1b"))
+    eng = ContinuousEngine(cfg, init_params(cfg, rng_key), n_slots=2, max_len=32, page=8)
+
+    def per_layer_walk(blocks):
+        def layer(c, block):
+            return c, jax.tree.map(lambda a: a.at[1, 0].add(1.0), block)
+        return jax.lax.scan(layer, 0, blocks["stack"])[1]
+
+    text = jax.jit(per_layer_walk).lower(eng.pool.blocks).compile().as_text()
+    assert _pool_copies(text, eng.pool.blocks)
+
+
+@pytest.mark.parametrize("attn_kernel", ["xla", "pallas"])
+def test_session_pool_is_never_copied(rng_key, attn_kernel):
+    """A short run through every pool-writing program (prefill joins,
+    decode steps at two table widths, a join from a partly filled cached
+    page, which clones it): each donated its pool, so ``pool_copied``
+    stays 0, and the pool the engine started with was consumed."""
+    cfg = reduced(get_config("llama3.2-1b"))
+    params = init_params(cfg, rng_key)
+    eng = ContinuousEngine(cfg, params, n_slots=2, max_len=32, page=8,
+                           attn_kernel=attn_kernel)
+    eng.enable_prefix_cache()
+    first = eng.pool.blocks
+    prompt = np.arange(1, 13, dtype=np.int32)
+    (a,) = eng.serve([Request(prompt=prompt, max_new=4)])
+    # positions 0..14 are written: 8 in a full page, 7 in a partial one
+    follow = np.concatenate([prompt, np.asarray(a.out[:3], np.int32),
+                             np.arange(50, 55, dtype=np.int32)])
+    (b,) = eng.serve([Request(prompt=follow, max_new=3)])
+    assert len(b.out) == 3
+    assert {p[0] for p in eng._pool_programs} == {"join", "decode", "clone"}
+    assert eng.pool_copied == 0
+    assert all(leaf.is_deleted() for leaf in jax.tree.leaves(first))
+    assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(eng.pool.blocks))
+
+
+def test_pool_copied_counts_a_program_that_kept_its_input(rng_key):
+    """A program whose pool was not donated wrote a copy: counted once,
+    on its first call only."""
+    cfg = reduced(get_config("llama3.2-1b"))
+    params = init_params(cfg, rng_key)
+    eng = ContinuousEngine(cfg, params, n_slots=2, max_len=32, page=8)
+    eng._join = jax.jit(eng._join.__wrapped__)          # no donation
+    prompt = np.arange(1, 13, dtype=np.int32)
+    eng.serve([Request(prompt=prompt, max_new=2), Request(prompt=prompt, max_new=2)])
+    assert eng.pool_copied == 1
